@@ -16,11 +16,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))          # repo root, for mpisppy_tpu
 
 from mpisppy_tpu.utils.platform import (  # noqa: E402
-    enable_compile_cache_if_cpu, enable_f64_if_cpu, ensure_cpu_backend)
+    enable_compile_cache, enable_f64_if_cpu, ensure_cpu_backend)
 
 ensure_cpu_backend()        # no-op unless JAX_PLATFORMS requests cpu
 enable_f64_if_cpu()         # CPU runs follow the f64 protocol
-enable_compile_cache_if_cpu()   # repeat runs skip ~30 s of compiles
+enable_compile_cache()      # repeat runs skip ~30 s of compiles
 
 from mpisppy_tpu.utils import amalgamator, config  # noqa: E402
 

@@ -40,7 +40,7 @@ def main():
     mult = int(os.environ.get("PALLAS_BENCH_MULT", 100))
     S = int(os.environ.get("PALLAS_BENCH_SCENS", 64))
     n_steps = int(os.environ.get("PALLAS_BENCH_STEPS", 200))
-    tile_s = int(os.environ.get("PALLAS_BENCH_TILE", 1))
+    tile_s = int(os.environ.get("PALLAS_BENCH_TILE", 8))   # multiple of 8
 
     b = farmer.build_batch(S, crops_multiplier=mult,
                            dtype=np.float32 if on_tpu else np.float64)

@@ -132,9 +132,15 @@ def run_spoke_from_spec(specfile: str) -> int:
     """Worker entry: reconstruct the spoke and serve until killed."""
     from ..utils.platform import ensure_cpu_backend
     ensure_cpu_backend()
+    import jax
 
     with open(specfile) as f:
         spec = json.load(f)
+    # the pin is deliberate (spawn_spoke): say so in the spoke's log
+    print(f"spoke {os.path.basename(specfile)}: jax backend "
+          f"{jax.default_backend()} "
+          f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r})",
+          flush=True)
 
     from .spcommunicator import WindowPair
 

@@ -269,8 +269,7 @@ class SparseSplitA(SplitA):
     touch only `vals` and work unchanged.
 
     Built by `sparsify_split` when the shared density is below the
-    solver's `sparse_threshold` knob (dense fallback above it, and
-    whenever jax.experimental.sparse is unavailable)."""
+    solver's `sparse_threshold` knob (dense above it)."""
 
     def to_dense(self):
         S = self.vals.shape[0]
@@ -319,9 +318,7 @@ def sparsify_split(A, threshold):
     """Convert a dense-shared SplitA to a SparseSplitA when its shared
     block's density is below `threshold` (host-side, once per prep —
     never inside a trace).  Returns `A` unchanged when the threshold is
-    off (<= 0), the density is at/above it, `A` is not a SplitA, or
-    jax.experimental.sparse is unavailable (the dense fallback the
-    mixed-precision docs promise)."""
+    off (<= 0), the density is at/above it, or `A` is not a SplitA."""
     if threshold is None or float(threshold) <= 0.0:
         return A
     if not isinstance(A, SplitA) or isinstance(A, SparseSplitA):
@@ -329,10 +326,7 @@ def sparsify_split(A, threshold):
     dens = shared_density(A)
     if dens >= float(threshold):
         return A
-    try:
-        from jax.experimental import sparse as jsparse
-    except ImportError:        # pragma: no cover - jax always has it
-        return A
+    from jax.experimental import sparse as jsparse
     sh = np.asarray(A.shared)
     nse = max(int(np.count_nonzero(sh)), 1)
     bcoo = jsparse.BCOO.fromdense(jnp.asarray(A.shared), nse=nse)
@@ -377,6 +371,14 @@ def delta_idx(batch):
     return meta.get("A_delta_idx")
 
 
+# Batched matvecs run at HIGHEST precision.  On a TPU the default
+# precision computes an f32 matmul in one bf16 pass; there the golden
+# farmer drive's PDHG solves never met their tolerance (every solve ran
+# to max_iters, PH stalled at conv 0.26) while HIGHEST reproduced the
+# CPU golden (chip run, PR 21).  The CPU backend ignores the flag.
+_PREC = jax.lax.Precision.HIGHEST
+
+
 def bmatvec(A, x):
     """Batched A @ x: A (SA, M, N) with SA == S or SA == 1 (shared
     constraint matrix), or a SplitA; x (S, N) -> (S, M).
@@ -392,21 +394,23 @@ def bmatvec(A, x):
     dispatches on the BCOO type), dropping the dense M*N FLOPs per
     scenario to nnz(shared)."""
     if isinstance(A, SplitA):
-        out = x @ A.shared.T
+        out = (x @ A.shared.T if isinstance(A, SparseSplitA)
+               else jnp.matmul(x, A.shared.T, precision=_PREC))
         return out.at[:, A.rows].add(A.vals * jnp.take(x, A.cols, axis=1))
     if A.shape[0] == 1:
-        return x @ A[0].T
-    return jnp.einsum("smn,sn->sm", A, x)
+        return jnp.matmul(x, A[0].T, precision=_PREC)
+    return jnp.einsum("smn,sn->sm", A, x, precision=_PREC)
 
 
 def bmatvec_t(A, y):
     """Batched A^T @ y: A (SA, M, N) or SplitA, y (S, M) -> (S, N)."""
     if isinstance(A, SplitA):
-        out = y @ A.shared
+        out = (y @ A.shared if isinstance(A, SparseSplitA)
+               else jnp.matmul(y, A.shared, precision=_PREC))
         return out.at[:, A.cols].add(A.vals * jnp.take(y, A.rows, axis=1))
     if A.shape[0] == 1:
-        return y @ A[0]
-    return jnp.einsum("smn,sm->sn", A, y)
+        return jnp.matmul(y, A[0], precision=_PREC)
+    return jnp.einsum("smn,sm->sn", A, y, precision=_PREC)
 
 
 def node_segment_sum(node_of, num_nodes):

@@ -71,6 +71,7 @@ import numpy as np
 
 from .. import telemetry as _telemetry
 from ..resilience.bounds import PayloadGuard, payload_checksum
+from .exchange import device_f64, from_words, to_words
 from .slice_plan import slab_width
 
 HEADER_LANES = 3                   # [write_id, crc32, payload_len]
@@ -235,9 +236,9 @@ class CollectiveWindow:
             if slab.dev is None:
                 import jax
                 return jax.numpy.zeros(self.length), wid
-            return (slab.dev[self.lane,
-                             HEADER_LANES:HEADER_LANES + self.length],
-                    wid)
+            return (device_f64(slab.dev[
+                self.lane,
+                2 * HEADER_LANES:2 * (HEADER_LANES + self.length)]), wid)
 
     def corrupt_next_write(self):
         """Chaos hook (corrupt_window mode) — see Window."""
@@ -296,6 +297,11 @@ class CollectiveFabric:
         """Total device-program traces (the single-compile assertion:
         one per slab geometry — regrow retraces, steady state never)."""
         return self._up.traces + self._down.traces
+
+    @property
+    def windows(self):
+        """Every CollectiveWindow of the fabric, both directions."""
+        return self._down.windows + self._up.windows
 
     def add_pair(self, hub_length, spoke_length, tag=None):
         """Register one hub<->spoke pair as lane row `n_lanes` of both
@@ -383,13 +389,14 @@ class CollectiveFabric:
             if k_pad != k:
                 snap = np.concatenate(
                     [snap, np.zeros((k_pad - k, snap.shape[1]))])
-            out = self._run_program(slab, snap)
+            # the device moves the rows' uint32 words (exchange.to_words)
+            out = self._run_program(slab, to_words(snap))
             t0 = time.perf_counter()
             out.block_until_ready()
             self._h_latency.observe(time.perf_counter() - t0)
             self._c_coll.inc()
             self._c_bytes.inc(snap.nbytes)
-            host = np.asarray(out)    # host mirror, outside the timing
+            host = from_words(out)    # host mirror, outside the timing
             with slab.lock:
                 if gen > slab.committed_gen:
                     # the OLD slab.dev stays alive (and readable) until

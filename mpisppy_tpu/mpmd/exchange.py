@@ -33,6 +33,32 @@ from .. import telemetry as _telemetry
 from ..resilience.bounds import PayloadGuard, payload_checksum
 
 
+# Mailboxes move the float64 payload's BIT PATTERN as uint32 words.
+# Without jax_enable_x64 — the TPU's f32 default — a float64 array put
+# on a device is narrowed to float32, which broke every payload's CRC
+# (reproduced on CPU with x64 off, PR 21); words cross any backend
+# exactly.
+
+def to_words(values):
+    """float64 array (..., n) -> its uint32 words (..., 2n)."""
+    return np.ascontiguousarray(values, dtype=np.float64).view(np.uint32)
+
+
+def from_words(words):
+    """uint32 words (..., 2n) fetched from a device -> float64 (..., n)."""
+    return np.ascontiguousarray(np.asarray(words),
+                                dtype=np.uint32).view(np.float64)
+
+
+def device_f64(words):
+    """Device-side from_words for read_device consumers (a float64
+    device array needs jax_enable_x64)."""
+    import jax
+    import jax.numpy as jnp
+    return jax.lax.bitcast_convert_type(
+        words.reshape(words.shape[:-1] + (-1, 2)), jnp.float64)
+
+
 class DeviceWindow:
     """Drop-in for cylinders.spcommunicator.Window whose payload is a
     committed device array.
@@ -64,7 +90,7 @@ class DeviceWindow:
 
     def _put(self, values):
         import jax
-        return jax.device_put(values, self.device)
+        return jax.device_put(to_words(values), self.device)
 
     @property
     def write_id(self):
@@ -111,7 +137,7 @@ class DeviceWindow:
             if wid == self._last_read_wid and wid > 0:
                 self._c_stale.inc()
             self._last_read_wid = wid
-        return np.asarray(arr, dtype=np.float64), wid
+        return from_words(arr), wid
 
     def read_checked(self):
         """(data, write_id, ok, reason) — one snapshot, integrity
@@ -120,7 +146,7 @@ class DeviceWindow:
         own traffic accounting."""
         with self._lock:
             arr, wid, chk = self._payload, self._wid, self._checksum
-        data = np.asarray(arr, dtype=np.float64)
+        data = from_words(arr)
         ok, reason = self._pguard.check(data, wid, chk)
         if wid != self.KILL:
             if not ok or (wid == self._last_read_wid and wid > 0):
@@ -135,9 +161,9 @@ class DeviceWindow:
     def read_device(self):
         """(device-resident payload, write_id) without a host copy —
         for consumers that feed the vector straight into a jitted
-        program on the reader's slice."""
+        program on the reader's slice (float64: needs x64)."""
         with self._lock:
-            return self._payload, self._wid
+            return device_f64(self._payload), self._wid
 
     def send_kill(self):
         with self._lock:

@@ -18,17 +18,17 @@ See /opt/skills/guides/pallas_guide.md for the API conventions used.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:                                                  # TPU-only module
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:                                   # pragma: no cover
-    _VMEM = None
+# VMEM the kernel may ask Mosaic for (the scoped limit it is compiled
+# with).  A v5e core has 128 MiB; the rest is Mosaic's own headroom.
+VMEM_BUDGET = 100 * 2**20
 
 
 def _chunk_kernel(n_steps, A_ref, cs_ref, qs_ref, lb_ref, ub_ref,
@@ -75,6 +75,59 @@ def _chunk_kernel(n_steps, A_ref, cs_ref, qs_ref, lb_ref, ub_ref,
     ys_ref[:] = ys
 
 
+def _block_bytes(shape, itemsize):
+    """VMEM bytes of one block as Mosaic lays it out: the last dim
+    padded to 128 lanes, the second-to-last to the sublane tile (8 rows
+    of 32-bit, 16 of bf16)."""
+    *lead, rows, cols = shape
+    sub = 8 * max(1, 4 // itemsize)
+    return (math.prod(lead) * (-(-rows // sub) * sub)
+            * (-(-cols // 128) * 128) * itemsize)
+
+
+def vmem_bytes(tile, M, N, a_itemsize, itemsize=4, steps=2):
+    """Estimated VMEM of one grid step: every in/out block (twice when
+    the grid has more than one step — Pallas double-buffers them), plus
+    the kernel's (tile, M, N) temporaries in the state dtype (the
+    broadcast product, and the cast copy of a narrower A).  Checked
+    against compiles for a described v5e: it stays above what Mosaic
+    reports."""
+    vec = (6 * _block_bytes((tile, N), itemsize)
+           + 4 * _block_bytes((tile, M), itemsize)
+           + 2 * _block_bytes((tile, 1), itemsize))
+    blocks = _block_bytes((tile, M, N), a_itemsize) + vec
+    slab = _block_bytes((tile, M, N), itemsize)
+    temps = 1.35 * slab + (slab if a_itemsize != itemsize else 0)
+    return int((2 if steps > 1 else 1) * blocks + temps)
+
+
+def choose_tile(S, M, N, tile_s, a_itemsize, itemsize=4):
+    """(tile, padded S) for a batch of S scenarios.  Every tile obeys
+    the TPU block rule — the scenario dim of a (tile, ·) block is a
+    multiple of 8 or the whole batch — and fits VMEM_BUDGET; S is
+    padded up to a multiple of 8.  Raises ValueError when even
+    the smallest legal tile does not fit."""
+    if tile_s < 1 or tile_s % 8:
+        raise ValueError(
+            f"pallas tile_s must be a positive multiple of 8 (the TPU "
+            f"sublane tile), got {tile_s}")
+    # pad to the next multiple of 8 at most, then take the largest
+    # tile up to tile_s that divides it
+    Sp = S if S <= 8 else -(-S // 8) * 8
+    tiles = ([S] if S <= 8 else
+             [t for t in range(min(tile_s, Sp), 0, -8) if Sp % t == 0])
+    for t in tiles:
+        need = vmem_bytes(t, M, N, a_itemsize, itemsize, steps=Sp // t)
+        if need <= VMEM_BUDGET:
+            return t, Sp
+    raise ValueError(
+        f"fused_chunk: a ({tiles[-1]}, {M}, {N}) scenario tile needs "
+        f"~{need / 2**20:.0f} MiB of VMEM, over the "
+        f"{VMEM_BUDGET / 2**20:.0f} MiB budget; this kernel holds whole "
+        f"per-scenario matrices in VMEM, so M*N this large needs the "
+        f"XLA path (use_pallas=False)")
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_steps", "tile_s", "interpret"))
@@ -85,52 +138,63 @@ def fused_chunk(A, cs, qs, lbs, ubs, rlo, rhi, x, y, tau, sigma,
     All arrays are SOLVER-SPACE (already Ruiz-scaled) like the inner
     loop of PDHGSolver._solve_impl.  tau/sigma: (S,) per-scenario step
     sizes.  Returns (x, y, x_sum, y_sum) exactly matching the jnp
-    `steps` implementation.
+    `steps` implementation.  A batch over 8 is padded to a multiple
+    of 8 (`choose_tile`) with inert scenarios — zero data, unit step
+    sizes — that are sliced off the outputs.
     """
     S, M, N = A.shape
-    # shrink the tile to the largest divisor of S <= tile_s by halving:
-    # compacted slabs (PDHGSolver.solve_compacted) arrive at power-of-
-    # two widths, so a pow2 tile_s degrades gracefully (8 -> 4 -> 2)
-    # instead of collapsing straight to 1 whenever S % tile_s != 0
-    tile_s = max(1, min(int(tile_s), S))
-    while S % tile_s:
-        tile_s -= 1 if tile_s % 2 else tile_s // 2
-    grid = (S // tile_s,)
+    tile, Sp = choose_tile(S, M, N, int(tile_s), A.dtype.itemsize,
+                           x.dtype.itemsize)
+    grid = (Sp // tile,)
     t2 = tau[:, None]
     s2 = sigma[:, None]
+    args = [A, cs, qs, lbs, ubs, rlo, rhi, x, y, t2, s2]
+    if Sp != S:
+        fills = [0.0] * 9 + [1.0, 1.0]     # unit tau/sigma: no 0/0
+
+        def pad(a, fill):
+            return jnp.concatenate(
+                [a, jnp.full((Sp - S,) + a.shape[1:], fill, a.dtype)])
+
+        args = [pad(a, v) for a, v in zip(args, fills)]
 
     def tile_spec(*blk):
-        return pl.BlockSpec(blk, lambda i: (i,) + (0,) * (len(blk) - 1))
+        # i * 0, not 0: block indices stay int32 under x64, where a
+        # literal 0 is int64 and Mosaic cannot lower the index map
+        return pl.BlockSpec(
+            blk, lambda i: (i,) + (i * 0,) * (len(blk) - 1))
 
     kernel = functools.partial(_chunk_kernel, n_steps)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            tile_spec(tile_s, M, N),    # A
-            tile_spec(tile_s, N),       # cs
-            tile_spec(tile_s, N),       # qs
-            tile_spec(tile_s, N),       # lb
-            tile_spec(tile_s, N),       # ub
-            tile_spec(tile_s, M),       # rlo
-            tile_spec(tile_s, M),       # rhi
-            tile_spec(tile_s, N),       # x
-            tile_spec(tile_s, M),       # y
-            tile_spec(tile_s, 1),       # tau
-            tile_spec(tile_s, 1),       # sigma
+            tile_spec(tile, M, N),      # A
+            tile_spec(tile, N),         # cs
+            tile_spec(tile, N),         # qs
+            tile_spec(tile, N),         # lb
+            tile_spec(tile, N),         # ub
+            tile_spec(tile, M),         # rlo
+            tile_spec(tile, M),         # rhi
+            tile_spec(tile, N),         # x
+            tile_spec(tile, M),         # y
+            tile_spec(tile, 1),         # tau
+            tile_spec(tile, 1),         # sigma
         ],
         out_specs=[
-            tile_spec(tile_s, N),
-            tile_spec(tile_s, M),
-            tile_spec(tile_s, N),
-            tile_spec(tile_s, M),
+            tile_spec(tile, N),
+            tile_spec(tile, M),
+            tile_spec(tile, N),
+            tile_spec(tile, M),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((S, N), x.dtype),
-            jax.ShapeDtypeStruct((S, M), y.dtype),
-            jax.ShapeDtypeStruct((S, N), x.dtype),
-            jax.ShapeDtypeStruct((S, M), y.dtype),
+            jax.ShapeDtypeStruct((Sp, N), x.dtype),
+            jax.ShapeDtypeStruct((Sp, M), y.dtype),
+            jax.ShapeDtypeStruct((Sp, N), x.dtype),
+            jax.ShapeDtypeStruct((Sp, M), y.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
-    )(A, cs, qs, lbs, ubs, rlo, rhi, x, y, t2, s2)
-    return tuple(out)
+    )(*args)
+    return tuple(o[:S] for o in out)

@@ -928,11 +928,17 @@ class PDHGSolver:
             sigma = 0.9 * omega / anorm
             tau = 0.9 / (omega * anorm + 0.9 * qmax)
 
-            if self.use_pallas and csum is None \
-                    and not isinstance(A, SplitA) \
-                    and A.shape[0] == x.shape[0]:
-                # (the Pallas chunk kernel tiles per-scenario A slabs;
-                # shared-A batches use the XLA matmul path)
+            if self.use_pallas:
+                # the Pallas chunk kernel tiles per-scenario A slabs; a
+                # caller who asked for it gets it compiled or an error,
+                # never a silent XLA or interpret-mode substitute
+                if csum is not None or isinstance(A, SplitA) \
+                        or A.shape[0] != x.shape[0]:
+                    raise ValueError(
+                        "use_pallas=True needs a dense per-scenario "
+                        "(S, M, N) operator and no consensus; this "
+                        "solve's operator is split, shared or "
+                        "consensus — use use_pallas=False")
                 from .pallas_pdhg import fused_chunk
                 return fused_chunk(
                     A, cs, qs, lbs, ubs, rlo, rhi, x, y,

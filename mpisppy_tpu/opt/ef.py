@@ -105,8 +105,7 @@ class ExtensiveForm(SPOpt):
             cpu = jax.devices("cpu")[0]
         except RuntimeError:
             cpu = None
-        from ..utils.platform import enable_x64_scope
-        with enable_x64_scope():
+        with jax.enable_x64(True):
             put = ((lambda a: jax.device_put(np.asarray(a, np.float64),
                                              cpu))
                    if cpu is not None

@@ -190,11 +190,9 @@ class ScenarioMesh:
         buffer to the program (the slab never detours through a fresh
         host allocation); `on_trace` fires at trace time only, the hook
         behind the single-compile-per-geometry assertion.
-        check_rep=False: with out_specs=P() the all-gather's output IS
-        replicated over `cyl`, but shard_map's replication checker
-        cannot infer that and would reject the specs."""
-        from jax.experimental.shard_map import shard_map
-
+        check_vma=False: with out_specs=P() the all-gather's output IS
+        replicated over `cyl`, but shard_map's varying-axes check
+        cannot infer that on a one-lane fabric and rejects the specs."""
         if not self.n_cyl:
             raise ValueError(
                 "fused_cyl_all_gather needs a 2-D cylinder mesh (n_cyl)")
@@ -205,8 +203,8 @@ class ScenarioMesh:
                 on_trace()
             return jax.lax.all_gather(x, axis, axis=0, tiled=True)
 
-        fn = shard_map(gather, mesh=self.mesh, in_specs=P(axis),
-                       out_specs=P(), check_rep=False)
+        fn = jax.shard_map(gather, mesh=self.mesh, in_specs=P(axis),
+                           out_specs=P(), check_vma=False)
         jfn = jax.jit(fn, in_shardings=self.lane_sharding(),
                       out_shardings=self.replicated(),
                       donate_argnums=(0,) if donate else ())

@@ -1,7 +1,8 @@
 """Build + ctypes bindings for the native runtime (runtime/exchange.cpp).
 
-Compiles the shared library on first use with g++ (toolchain is part
-of the target environment), caching by source mtime.  If no compiler
+Compiles the shared library from exchange.cpp on first use with g++
+(toolchain is part of the target environment), caching by source
+mtime; the built .so is never committed.  If no compiler
 is available the import still succeeds, `available()` returns False,
 and NativeWindow transparently delegates to PySeqlockWindow — a pure
 numpy-over-mmap implementation of the SAME memory layout (24-byte
@@ -31,9 +32,14 @@ _tried = False
 
 
 def _build():
+    """Compile exchange.cpp (the only source of the library: the .so is
+    never committed).  Built under a private name and renamed into
+    place, so concurrent first uses never load a half-written file."""
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", _LIB, _SRC]
+           "-o", tmp, _SRC]
     subprocess.run(cmd, check=True, capture_output=True)
+    os.replace(tmp, _LIB)
 
 
 def _load():
@@ -49,8 +55,8 @@ def _load():
             try:
                 lib = ctypes.CDLL(_LIB)
             except OSError:
-                # a checked-out .so may target another toolchain/ABI;
-                # one rebuild from source is authoritative
+                # a stale .so may target another toolchain/ABI; one
+                # rebuild from source is authoritative
                 _build()
                 lib = ctypes.CDLL(_LIB)
         except (OSError, subprocess.CalledProcessError):

@@ -23,9 +23,9 @@ Per bucket this module holds:
 
 AOT persistence to disk
 -----------------------
-When `MPISPPY_TPU_COMPILE_CACHE_DIR` is set, each batched executable
-is additionally serialized via `jax.export.export(...).serialize()`
-into `$MPISPPY_TPU_COMPILE_CACHE_DIR/aot/<fingerprint>.mtaot`, under a
+Each batched executable is additionally serialized via
+`jax.export.export(...).serialize()` into
+`<compile cache root>/aot/<fingerprint>.mtaot` (`aot_cache_dir()`), under a
 fingerprint covering the full `bucket_key` PLUS batch width, jax and
 jaxlib versions, backend, argument treedef, and the x64 flag — the
 things that can silently change the traced program between processes.
@@ -125,12 +125,11 @@ def bucket_key(batch, options=None, model=None, backend=None):
 # -- AOT disk layer --------------------------------------------------------
 
 def aot_cache_dir():
-    """The on-disk AOT executable directory, or None when persistence
-    is off (`MPISPPY_TPU_COMPILE_CACHE_DIR` unset/empty)."""
-    root = os.environ.get("MPISPPY_TPU_COMPILE_CACHE_DIR")
-    if not root:
-        return None
-    return os.path.join(root, "aot")
+    """The on-disk AOT executable directory: `aot/` under the one
+    compile-cache root (utils.platform.compile_cache_root — jax's own
+    JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache)."""
+    from ..utils.platform import compile_cache_root
+    return os.path.join(compile_cache_root(), "aot")
 
 
 def aot_fingerprint(key, B, treedef_repr):
@@ -211,8 +210,8 @@ _PREWARMED = {}                        # fingerprint -> jax.export.Exported
 
 def prewarm(directory=None):
     """Load the full AOT artifact set into the resident prewarm
-    registry.  `directory` defaults to `aot_cache_dir()` (None → no-op,
-    returns 0).  Undecodable/foreign files are skipped and counted in
+    registry.  `directory` defaults to `aot_cache_dir()` (a missing
+    directory → no-op, returns 0).  Undecodable/foreign files are skipped and counted in
     `cache.aot_load_failures`.  Returns the number of artifacts
     resident after the sweep."""
     from jax import export as jax_export
@@ -410,15 +409,11 @@ class CompiledBucket:
         leaves, in_treedef = tu.tree_flatten(args)
         # superstep out = a PHState shaped like the (stacked) state in
         out_treedef = tu.tree_structure(args[0])
-        path = None
-        d = aot_cache_dir()
         fp = aot_fingerprint(self.key, B, repr(in_treedef))
-        if d is not None:
-            path = os.path.join(d, fp + _AOT_SUFFIX)
-            exported = self._aot_load(path, fp)
-            if exported is not None:
-                return _BatchedRunner(jax.jit(exported.call),
-                                      out_treedef)
+        path = os.path.join(aot_cache_dir(), fp + _AOT_SUFFIX)
+        exported = self._aot_load(path, fp)
+        if exported is not None:
+            return _BatchedRunner(jax.jit(exported.call), out_treedef)
 
         # trace path: export the flat-leaf wrapper (custom pytrees like
         # PHState/ScenarioBatch don't cross jax.export's serialization
@@ -443,8 +438,7 @@ class CompiledBucket:
             fn = jax.jit(jax.vmap(
                 functools.partial(ph_superstep, self.solver)))
             return fn.lower(*args).compile()
-        if path is not None:
-            self._aot_save(path, fp, B, exported)
+        self._aot_save(path, fp, B, exported)
         return _BatchedRunner(jax.jit(exported.call), out_treedef)
 
 

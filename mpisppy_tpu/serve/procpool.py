@@ -13,7 +13,7 @@ backed by a `serve/procworker.py` child process per slot:
     on a loopback socket through a pooled, pipelined `PooledClient`
     (persistent sockets, multiple in-flight frames);
   * workers boot warm: they `prewarm()` the shared
-    `MPISPPY_TPU_COMPILE_CACHE_DIR/aot` artifact set, so a rolled or
+    `aot/` artifact set under the compile-cache root, so a rolled or
     replaced incarnation serves its first request without re-tracing;
   * process DEATH (kill -9, OOM, a segfaulting native op) is a
     first-class health signal: `health()` checks the child's exit
@@ -107,17 +107,27 @@ def _detect_x64():
     return None
 
 
-def _detect_force_cpu():
-    """Mirror the parent's backend pinning: a parent already running
-    jax on CPU forces the worker onto CPU too (the tests' 8-virtual-
-    device topology crosses via the inherited XLA_FLAGS env)."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return jax.default_backend() == "cpu"
-    except Exception:                  # pragma: no cover - not init'd
-        return False
+def _parent_backend():
+    """The parent's jax platform when jax is loaded AND its backends
+    are initialized, else None.  Never initializes a backend itself: a
+    parent that did would hold the chip its workers need."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return sys.modules["jax"].default_backend()
+
+
+def _one_process_per_chip(n, platform, why):
+    return RuntimeError(
+        f"serve_replica_mode='process' cannot start {n} worker(s) on "
+        f"the {platform!r} backend: {why}.  Each worker opens every "
+        f"accelerator chip of the host, and libtpu lets one process "
+        f"hold them: a second process fails at start-up on the libtpu "
+        f"lockfile (observed on a TPU v5e).  Run one process replica "
+        f"per host from a router that has not touched the device, or "
+        f"serve_replica_mode='thread'.")
 
 
 class ProcReplica:
@@ -143,6 +153,7 @@ class ProcReplica:
         self.proc = None
         self.pid = None
         self.port = None
+        self.platform = None    # the worker's jax backend, from its portfile
         self.client = None
         self.boot_seconds = None       # worker-reported service boot
         self.spawn_seconds = None      # parent-observed spawn -> ready
@@ -171,7 +182,10 @@ class ProcReplica:
             "token": self.token,
             "portfile": self._portfile,
             "x64": _detect_x64(),
-            "force_cpu": _detect_force_cpu(),
+            # mirror the parent's pinning: a parent running jax on the
+            # CPU puts the worker there too (the tests' 8 virtual
+            # devices cross via the inherited XLA_FLAGS)
+            "force_cpu": _parent_backend() == "cpu",
         }
         cfgfile = os.path.join(self.workdir, f"cfg_{self.name}.json")
         with open(cfgfile, "w") as f:
@@ -231,6 +245,7 @@ class ProcReplica:
         with open(self._portfile) as f:
             info = json.load(f)
         self.port = int(info["port"])
+        self.platform = info.get("platform")
         self.boot_seconds = info.get("boot_seconds")
         self.prewarm_loaded = int(info.get("prewarm_loaded", 0))
         self.spawn_seconds = time.monotonic() - self._spawned_at
@@ -543,9 +558,26 @@ class ProcReplicaSet:
 
     def start(self):
         """Spawn EVERY worker first, then wait on all — N boots cost
-        max(boot), not sum(boot)."""
+        max(boot), not sum(boot).  On an accelerator only one process
+        can hold the chips, so the set refuses to start a second worker
+        there: unless the parent runs jax on the CPU or JAX_PLATFORMS=cpu
+        pins the workers there, slot 0 boots alone and reports its
+        backend before the rest are spawned."""
         if self._started:
             return self
+        from ..utils.platform import cpu_requested
+        parent = _parent_backend()
+        if parent not in (None, "cpu"):
+            self.shutdown()
+            raise _one_process_per_chip(
+                self.n, parent, "this router process already holds it")
+        if parent is None and self.n > 1 and not cpu_requested():
+            first = self.replicas[0].start()
+            if first.platform != "cpu":
+                self.shutdown()
+                raise _one_process_per_chip(
+                    self.n, first.platform,
+                    "worker p0 took the chips at boot")
         for r in self.replicas:
             if r.proc is None:
                 r.spawn()

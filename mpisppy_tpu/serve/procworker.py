@@ -20,7 +20,7 @@ Boot sequence (the order matters):
   4. `ensure_cpu_backend(force=cfg["force_cpu"])` — mirror the parent's
      backend choice;
   5. build + start the service, `prewarm()` the shared AOT artifact
-     dir (`MPISPPY_TPU_COMPILE_CACHE_DIR/aot`, inherited env) so the
+     dir (`compile_cache.aot_cache_dir()`, inherited env) so the
      first request of every previously-seen shape runs warm;
   6. bind 127.0.0.1:0, then atomically write the portfile — the parent
      polls for it; a complete portfile means "ready to serve".
@@ -315,9 +315,11 @@ def main(argv=None):
     portfile = cfg["portfile"]
     tmp = portfile + ".tmp"
     with open(tmp, "w") as f:
+        import jax
         json.dump({"port": server.port, "pid": os.getpid(),
                    "boot_seconds": server.boot_seconds,
-                   "prewarm_loaded": server.prewarm_loaded}, f)
+                   "prewarm_loaded": server.prewarm_loaded,
+                   "platform": jax.default_backend()}, f)
     os.replace(tmp, portfile)
     server.wait()
     return 0

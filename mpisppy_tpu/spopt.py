@@ -31,6 +31,20 @@ from .spbase import SPBase
 from .utils import mfu as _mfu
 
 
+def _host(a):
+    """Host (numpy) copy of `a`.  A jax.Array that spans devices of
+    other processes (multi-host meshes) cannot be fetched directly: a
+    replicated one is read from a local copy, a sharded one is
+    allgathered — a collective, which every process reaches in the same
+    order because solve_loop runs SPMD."""
+    if isinstance(a, jax.Array) and not a.is_fully_addressable:
+        if a.is_fully_replicated:
+            return np.asarray(a.addressable_data(0))
+        from jax.experimental import multihost_utils
+        return np.asarray(multihost_utils.process_allgather(a, tiled=True))
+    return np.asarray(a)
+
+
 class SPOpt(SPBase):
     # subclasses needing one column scaling shared across scenarios
     # (consensus/EF solves) set this so the batch is prepared once
@@ -272,7 +286,7 @@ class SPOpt(SPBase):
             res = solver.solve_compacted(
                 *args, **kw, probs=b.prob, on_segment=traj.append)
             self._active_traj = traj
-            full = float(max(int(np.sum(np.asarray(b.prob) > 0)), 1))
+            full = float(max(int(np.sum(_host(b.prob) > 0)), 1))
             self._active_fraction = (traj[-1]["active"] / full
                                      if traj else 0.0)
             # FLOPs the compacted segments did NOT spend on rows the
@@ -288,11 +302,11 @@ class SPOpt(SPBase):
             res = solver.solve(*args, **kw, iters_cap=iters_cap)
             saved = 0.0
             self._active_fraction = float(
-                np.sum(np.asarray(~res.converged)
-                       & (np.asarray(b.prob) > 0))
-                / max(int(np.sum(np.asarray(b.prob) > 0)), 1))
-        it_n = int(res.iters)
-        rst_n = int(np.sum(np.asarray(res.restarts)))
+                np.sum(~_host(res.converged)
+                       & (_host(b.prob) > 0))
+                / max(int(np.sum(_host(b.prob) > 0)), 1))
+        it_n = int(_host(res.iters))
+        rst_n = int(np.sum(_host(res.restarts)))
         # net of compaction savings: saved counts work NOT done
         self._flops += _mfu.pdhg_flops(
             it_n, b.num_scens, b.num_rows, b.num_vars,
@@ -307,7 +321,7 @@ class SPOpt(SPBase):
             select = None
             if certify == "feas":
                 tol = 10 * float(self.solver_eps)
-                select = np.asarray(res.pres) >= tol
+                select = _host(res.pres) >= tol
             res = self._certified_resolve(res, c, qdiag, lb, ub,
                                           select=select)
         if batch is not None:
@@ -332,7 +346,7 @@ class SPOpt(SPBase):
             r.gauge("pdhg.active_fraction").set(self._active_fraction)
             r.gauge("pdhg.active_scenarios").set(
                 self._active_fraction
-                * int(np.sum(np.asarray(b.prob) > 0)))
+                * int(np.sum(_host(b.prob) > 0)))
             if saved:
                 r.counter("pdhg.flops_saved").inc(saved)
             if isinstance(prep.A, SparseSplitA):
@@ -350,8 +364,8 @@ class SPOpt(SPBase):
         if dtiming or self.options.get("display_timing"):
             self._solve_times.append(dt)
             global_toc(f"solve_loop: {dt*1e3:8.1f} ms, "
-                       f"iters={int(res.iters)}, "
-                       f"conv={int(np.sum(np.asarray(res.converged)))}"
+                       f"iters={int(_host(res.iters))}, "
+                       f"conv={int(np.sum(_host(res.converged)))}"
                        f"/{b.num_scens}")
         return res
 
@@ -385,8 +399,8 @@ class SPOpt(SPBase):
         Ruiz/anorm depend only on A, so the full-batch f64 prep is
         computed once per key and indexed per call.
         """
-        conv = np.asarray(res.converged)
-        live = np.asarray(self.batch.prob) > 0
+        conv = _host(res.converged)
+        live = _host(self.batch.prob) > 0
         pick = ~conv if select is None else np.asarray(select)
         idx = np.flatnonzero(pick & live)
         if idx.size == 0:
@@ -434,8 +448,7 @@ class SPOpt(SPBase):
             cpu = jax.devices("cpu")[0]
         except RuntimeError:
             cpu = None
-        from .utils.platform import enable_x64_scope
-        with enable_x64_scope():
+        with jax.enable_x64(True):
             put = ((lambda a: jax.device_put(a, cpu))
                    if cpu is not None else jnp.asarray)
             full = self._np_cache.get(prep_key)

@@ -8,32 +8,33 @@ reports `mfu` and `iters_per_sec`.
 
 Peak numbers are dtype-aware:
 
-- TPU: per-chip dense matmul peaks from public specs
-  (jax-ml.github.io/scaling-book hardware table).  MXU f32 runs at
-  half the bf16 rate on most generations; f64 is emulated an order of
-  magnitude below f32 (no native f64 datapath), modeled here as
-  f32_peak / 10 — a rough but non-null denominator.
+- TPU: per-chip dense bf16 matmul peaks from Google Cloud's published
+  specs, keyed by the `device_kind` string the chip itself reports.
+  A device kind missing from the table is an error, never a default.
+  The MXU has no published f32 rate; f32 is modeled at half the bf16
+  rate, and f64 (emulated, no native datapath) at f32 / 10 — rough but
+  non-null denominators.
 - CPU: estimated from the host core count x a nominal frequency x
   SIMD FLOPs/cycle per dtype (AVX2-class FMA defaults: 32 f32, 16
   f64 FLOPs per core-cycle; bf16 has no wide CPU datapath and falls
   back to the f32 rate).  Override with env CPU_PEAK_FLOPS.  The
-  estimate is coarse — its job is making the MFU gauge populate on
-  the CPU-fallback bench rounds instead of reporting null — so treat
-  CPU MFU as a relative signal, not a calibrated one.
+  estimate is coarse: treat CPU MFU as a relative signal, never as a
+  device metric.
 """
 
 from __future__ import annotations
 
 import os
 
-# (bf16_peak, f32_peak) FLOP/s per chip
+# device_kind -> (bf16_peak, f32_peak) FLOP/s per chip.  bf16 peaks:
+# Google Cloud TPU documentation, per-chip "Peak compute per chip
+# (bf16)" of each generation's system-architecture page (v4 275, v5e
+# 197, v5p 459, v6e 918 TFLOP/s); f32 is the modeled half rate.
 _PEAKS = {
-    "v2": (45e12, 22.5e12),
-    "v3": (123e12, 61.5e12),
-    "v4": (275e12, 137.5e12),
-    "v5e": (197e12, 98.5e12),
-    "v5p": (459e12, 229.5e12),
-    "v6e": (918e12, 459e12),
+    "TPU v4": (275e12, 137.5e12),
+    "TPU v5 lite": (197e12, 98.5e12),      # v5e
+    "TPU v5": (459e12, 229.5e12),          # v5p
+    "TPU v6 lite": (918e12, 459e12),       # v6e
 }
 
 # TPUs emulate f64 in software well below the f32 rate; /10 keeps the
@@ -68,12 +69,10 @@ def cpu_peak_flops(dtype="float32"):
 
 
 def device_peak_flops(device=None, dtype="float32"):
-    """Best-effort peak FLOP/s for `device` (default: jax.devices()[0])
-    at `dtype`.  Override with env TPU_PEAK_FLOPS (wins on every
-    backend) or CPU_PEAK_FLOPS (hosts).  Never returns None: the CPU
-    path uses the
-    core-count x frequency x SIMD-width estimate above so the MFU
-    gauge populates on every backend."""
+    """Peak FLOP/s for `device` (default: jax.devices()[0]) at
+    `dtype`.  Override with env TPU_PEAK_FLOPS (wins on every backend)
+    or CPU_PEAK_FLOPS (hosts).  Raises ValueError for an accelerator
+    whose device_kind is not in _PEAKS."""
     env = os.environ.get("TPU_PEAK_FLOPS")
     if env:
         return float(env)
@@ -82,14 +81,14 @@ def device_peak_flops(device=None, dtype="float32"):
         device = jax.devices()[0]
     if device.platform == "cpu":
         return cpu_peak_flops(dtype)
-    kind = (getattr(device, "device_kind", "") or "").lower()
+    kind = getattr(device, "device_kind", "")
+    peaks = _PEAKS.get(kind)
+    if peaks is None:
+        raise ValueError(
+            f"no published peak for device kind {kind!r} in "
+            f"utils/mfu._PEAKS (known: {sorted(_PEAKS)}); add its "
+            f"per-chip peak with its source, or set TPU_PEAK_FLOPS")
     name = _dtype_name(dtype)
-    for key, peaks in _PEAKS.items():
-        if key in kind:
-            break
-    else:
-        # unknown TPU kind: assume v5e-class
-        peaks = _PEAKS["v5e"]
     if name == "bfloat16":
         return peaks[0]
     if name == "float64":

@@ -79,6 +79,22 @@ class TestCollectiveWindowContract:
         data, wid = to_hub.read()
         assert wid == 0 and np.array_equal(data, np.zeros(4))
 
+    @pytest.mark.parametrize("backend", ["collective", "device"])
+    def test_payload_exact_without_x64(self, backend, fresh_telemetry):
+        """The TPU runs f32 (x64 off), where a float64 array put on a
+        device narrows to float32: mailboxes move the payload's bit
+        pattern instead, so values and CRC survive any backend."""
+        vals = np.array([1.1, 2.2, 1e-300, -3.3e200, 5.5])
+        with jax.enable_x64(False):
+            if backend == "collective":
+                fab, [(win, _), _] = two_lane_fabric()
+            else:
+                win = DeviceWindow(5, device=jax.devices()[0])
+            win.write(vals)
+            data, wid, ok, reason = win.read_checked()
+        assert ok and reason is None and wid == 1
+        assert data.dtype == np.float64 and np.array_equal(data, vals)
+
     def test_shape_mismatch(self, fresh_telemetry):
         _, [(to_spoke, _), _] = two_lane_fabric()
         with pytest.raises(ValueError, match="expects shape"):

@@ -10,6 +10,9 @@ through the window files, the children exit cleanly on the kill signal,
 and the resulting bounds BRACKET the independently computed EF optimum.
 """
 
+import os
+import shutil
+
 import numpy as np
 import pytest
 
@@ -57,11 +60,17 @@ def test_multiproc_wheel_farmer():
                         "all_scenario_names": names},
          "proc": {"batch": batch_spec}},
     ]
-    ws = WheelSpinner(hub_dict, spoke_dicts, mode="multiproc").spin()
+    ws = WheelSpinner(hub_dict, spoke_dicts, mode="multiproc",
+                      keep_workdir=True).spin()
 
-    # children exited cleanly on the kill signal
+    # children exited cleanly on the kill signal, and each spoke's log
+    # names the backend it was pinned to
     for h in ws.spcomm.spokes:
         assert h.proc is not None and h.proc.returncode == 0
+        with open(h.proc.log_path) as f:
+            assert "jax backend cpu" in f.read()
+    shutil.rmtree(os.path.dirname(ws.spcomm.spokes[0].proc.log_path),
+                  ignore_errors=True)
 
     ib, ob = ws.BestInnerBound, ws.BestOuterBound
     assert np.isfinite(ob), "no outer bound crossed the process boundary"

@@ -1,6 +1,6 @@
 """Network front-door tests (ISSUE 19): the serve/net wire protocol,
 Gateway/Client over real loopback sockets, AOT executable persistence
-(`MPISPPY_TPU_COMPILE_CACHE_DIR`), and the zero-downtime rolling
+(`<compile cache root>/aot`), and the zero-downtime rolling
 restart — plus the package-hygiene and import-laziness guards.
 
 All tests are tier-1 (`net` marker, no `slow`): farmer-sized batches,
@@ -741,7 +741,7 @@ def test_aot_persistence_warm_start_skips_trace(tmp_path, monkeypatch):
     bitwise identical."""
     from mpisppy_tpu.serve.service import stack_superstep_args
 
-    monkeypatch.setenv("MPISPPY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     phs = _two_iter0_phs()
     args = stack_superstep_args(phs)
 
@@ -772,7 +772,7 @@ def test_aot_persistence_warm_start_skips_trace(tmp_path, monkeypatch):
 def test_aot_corrupt_entry_falls_back_to_trace(tmp_path, monkeypatch):
     from mpisppy_tpu.serve.service import stack_superstep_args
 
-    monkeypatch.setenv("MPISPPY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     phs = _two_iter0_phs()
     args = stack_superstep_args(phs)
     out1 = _run(cc.CompileCache().get(
@@ -799,7 +799,7 @@ def test_aot_fingerprint_mismatch_falls_back(tmp_path, monkeypatch):
     is rejected before deserialization — silent fallback, counted."""
     from mpisppy_tpu.serve.service import stack_superstep_args
 
-    monkeypatch.setenv("MPISPPY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     phs = _two_iter0_phs()
     args = stack_superstep_args(phs)
     out1 = _run(cc.CompileCache().get(
@@ -821,14 +821,17 @@ def test_aot_fingerprint_mismatch_falls_back(tmp_path, monkeypatch):
     assert _leaves_equal(out1, out2)
 
 
-def test_aot_disabled_without_cache_dir(tmp_path, monkeypatch):
-    monkeypatch.delenv("MPISPPY_TPU_COMPILE_CACHE_DIR", raising=False)
-    assert cc.aot_cache_dir() is None
+def test_aot_dir_follows_compile_cache_root(tmp_path, monkeypatch):
+    """The artifacts land under the one compile-cache root: jax's own
+    JAX_COMPILATION_CACHE_DIR, never a second serve-specific path."""
+    root = tmp_path / "jaxcache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(root))
+    assert cc.aot_cache_dir() == str(root / "aot")
     from mpisppy_tpu.serve.service import stack_superstep_args
     phs = _two_iter0_phs()
     args = stack_superstep_args(phs)
     cache = cc.CompileCache()
     _run(cache.get(phs[0].batch, FAST_OPTS,
                    model="farmer").batched_superstep(args), args)
-    s = cache.stats()
-    assert s["aot_saves"] == 0 and s["aot_loads"] == 0
+    assert cache.stats()["aot_saves"] == 1
+    assert len(list((root / "aot").glob("*" + cc._AOT_SUFFIX))) == 1

@@ -31,7 +31,7 @@ def _ref_steps(A, cs, qs, lbs, ubs, rlo, rhi, x, y, tau, sigma, n):
 
 def test_fused_chunk_matches_reference():
     rng = np.random.RandomState(0)
-    S, M, N = 4, 5, 7
+    S, M, N = 16, 5, 7
     A = jnp.asarray(rng.randn(S, M, N))
     cs = jnp.asarray(rng.randn(S, N))
     qs = jnp.asarray(np.abs(rng.randn(S, N)) * 0.1)
@@ -47,31 +47,59 @@ def test_fused_chunk_matches_reference():
 
     ref = _ref_steps(A, cs, qs, lbs, ubs, rlo, rhi, x, y, tau, sigma, 7)
     got = fused_chunk(A, cs, qs, lbs, ubs, rlo, rhi, x, y, tau, sigma,
-                      7, tile_s=2, interpret=True)
+                      7, tile_s=8, interpret=True)
     for r, g in zip(ref, got):
         assert np.allclose(np.asarray(r), np.asarray(g), atol=1e-10)
 
 
-def test_fused_chunk_odd_batch_falls_back_to_tile1():
+@pytest.mark.parametrize("S", [3, 12])
+def test_fused_chunk_odd_batch_pads_to_a_legal_tile(S):
+    """S <= 8 runs as one whole-batch block; S = 12 pads to 16 and runs
+    two tiles of 8 — both legal TPU block shapes."""
     rng = np.random.RandomState(1)
-    S, M, N = 3, 4, 5
+    M, N = 4, 5
     args = (jnp.asarray(rng.randn(S, M, N)), jnp.asarray(rng.randn(S, N)),
             jnp.zeros((S, N)), jnp.zeros((S, N)),
             jnp.full((S, N), 5.0), jnp.full((S, M), -1.0),
             jnp.ones((S, M)), jnp.asarray(rng.rand(S, N)),
             jnp.zeros((S, M)), jnp.full((S,), 0.1), jnp.full((S,), 0.1))
     out = fused_chunk(*args, 3, tile_s=8, interpret=True)
-    ref = _ref_steps(args[0], args[1], args[2], args[3], args[4],
-                     args[5], args[6], args[7], args[8], args[9],
-                     args[10], 3)
-    assert np.allclose(np.asarray(out[0]), np.asarray(ref[0]), atol=1e-10)
+    ref = _ref_steps(*args, 3)
+    for r, g in zip(ref, out):
+        assert g.shape == r.shape
+        assert np.allclose(np.asarray(g), np.asarray(r), atol=1e-10)
+
+
+def test_choose_tile_obeys_block_rule_and_budget():
+    from mpisppy_tpu.ops.pallas_pdhg import VMEM_BUDGET, choose_tile
+    assert choose_tile(3, 61, 120, 8, 4) == (3, 3)
+    assert choose_tile(12, 61, 120, 8, 4) == (8, 16)
+    assert choose_tile(64, 61, 120, 16, 4) == (16, 64)
+    assert choose_tile(40, 61, 120, 16, 4) == (8, 40)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        choose_tile(64, 61, 120, 4, 4)
+    with pytest.raises(ValueError, match="VMEM"):
+        choose_tile(64, 1200, 1500, 8, 4)
+    assert VMEM_BUDGET <= 128 * 2**20
+
+
+def test_pallas_refuses_operators_it_cannot_tile():
+    b = farmer.build_batch(8)
+    from mpisppy_tpu.ops.pdhg import prepare_batch_split
+    from mpisppy_tpu.ir import delta_idx
+    rows, cols = delta_idx(b)
+    prep = prepare_batch_split(b.A, rows, cols, b.row_lo, b.row_hi)
+    solver = PDHGSolver(max_iters=200, eps=1e-5, use_pallas=True,
+                        pallas_interpret=True)
+    with pytest.raises(ValueError, match="use_pallas"):
+        solver.solve(prep, b.c, b.qdiag, b.lb, b.ub)
 
 
 def test_pdhg_solver_pallas_path_farmer():
     b = farmer.build_batch(8)
     prep = prepare_batch(b.A, b.row_lo, b.row_hi)
     solver = PDHGSolver(max_iters=20000, eps=1e-7, use_pallas=True,
-                        pallas_tile=4, pallas_interpret=True)
+                        pallas_tile=8, pallas_interpret=True)
     res = solver.solve(prep, b.c, b.qdiag, b.lb, b.ub,
                        obj_const=b.obj_const)
     assert bool(np.asarray(res.converged).all())

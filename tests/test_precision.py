@@ -500,6 +500,21 @@ def test_peak_flops_dtype_aware_and_never_none(monkeypatch):
     assert mfu_mod.device_peak_flops(dev, dtype="float32") == 2e12
 
 
+def test_peak_table_keyed_by_device_kind(monkeypatch):
+    """Peaks are looked up by the device_kind the chip reports (a v5e
+    says "TPU v5 lite"); an unknown kind is an error, never v5e's."""
+    monkeypatch.delenv("TPU_PEAK_FLOPS", raising=False)
+
+    class Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    assert mfu_mod.device_peak_flops(Dev(), dtype="bfloat16") == 197e12
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="TPU v99"):
+        mfu_mod.device_peak_flops(Dev(), dtype="float32")
+
+
 def test_pdhg_flops_density_debit_and_mfu_non_null():
     full = mfu_mod.pdhg_flops(100, 8, 24, 16)
     half = mfu_mod.pdhg_flops(100, 8, 24, 16, density=0.5)

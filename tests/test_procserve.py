@@ -321,7 +321,7 @@ def test_prewarm_loads_artifacts_and_serves_hits(tmp_path, monkeypatch):
     """prewarm() makes the full artifact set resident; a fresh cache's
     next build is served from the registry (counted as a prewarm hit
     AND a load) without touching the disk file."""
-    monkeypatch.setenv("MPISPPY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     args, _, batch = _persist_one_artifact(tmp_path)
     (tmp_path / "aot" / ("junk" + cc._AOT_SUFFIX)).write_bytes(b"torn")
     cc.clear_prewarmed()
@@ -343,6 +343,41 @@ def test_prewarm_loads_artifacts_and_serves_hits(tmp_path, monkeypatch):
 
 
 # -- process-replica fleet (tentpole) --------------------------------------
+
+def test_process_fleet_refused_when_router_holds_the_chip(monkeypatch):
+    """A router process that already holds the accelerator leaves no
+    chip for any worker: refused before anything spawns."""
+    from mpisppy_tpu.serve import procpool
+    monkeypatch.setattr(procpool, "_parent_backend", lambda: "tpu")
+    rs = procpool.ProcReplicaSet({"serve_proc_workdir": None},
+                                 n_replicas=1)
+    with pytest.raises(RuntimeError, match="already holds it"):
+        rs.start()
+    assert all(r.proc is None for r in rs.replicas)
+
+
+def test_process_fleet_refuses_second_worker_on_accelerator(monkeypatch):
+    """Parent off jax: slot 0 boots alone, and when it reports an
+    accelerator the set refuses the rest (libtpu: one process per
+    host's chips) and shuts slot 0 down."""
+    from mpisppy_tpu.serve import procpool
+    monkeypatch.setattr(procpool, "_parent_backend", lambda: None)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    booted, stopped = [], []
+
+    def fake_start(self):
+        booted.append(self.slot)
+        self.platform = "tpu"
+        return self
+
+    monkeypatch.setattr(procpool.ProcReplica, "start", fake_start)
+    monkeypatch.setattr(procpool.ProcReplica, "shutdown",
+                        lambda self, timeout=5.0: stopped.append(self.slot))
+    rs = procpool.ProcReplicaSet({}, n_replicas=3)
+    with pytest.raises(RuntimeError, match="lockfile"):
+        rs.start()
+    assert booted == [0] and sorted(stopped) == [0, 1, 2]
+
 
 def _proc_router(n, **extra):
     o = {"serve_replicas": n, "serve_replica_mode": "process",
@@ -386,7 +421,7 @@ def test_sigkill_mid_batch_breaker_replacement_and_bitwise_replay(
     (prewarmed from the shared AOT dir), and replays the stranded
     request — whose result is bitwise-identical to PH.ph_main, and
     whose idempotent resubmit returns the same handle and result."""
-    monkeypatch.setenv("MPISPPY_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     _persist_one_artifact(tmp_path)    # replacement has something to prewarm
     cc.clear_prewarmed()
 

@@ -9,6 +9,7 @@ ops.pdhg.shared_solve_jit) amortize compiles across tests.
 """
 
 import ast
+import os
 import pathlib
 import subprocess
 import sys
@@ -115,22 +116,34 @@ def test_enable_compile_cache_env_dir(tmp_path, monkeypatch):
     import jax
 
     from mpisppy_tpu.utils import platform
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    monkeypatch.setenv("MPISPPY_TPU_COMPILE_CACHE_DIR",
-                       str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     old = jax.config.jax_compilation_cache_dir
     try:
         got = platform.enable_compile_cache()
         assert got == str(tmp_path / "cc")
         assert jax.config.jax_compilation_cache_dir == got
+        # the serve AOT artifacts live under the same root
+        assert cc.aot_cache_dir() == os.path.join(got, "aot")
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
 
 
-def test_enable_compile_cache_alias():
+@pytest.mark.parametrize("value,want", [
+    ("cpu", True), (" cpu ", True), ("tpu,cpu", False), ("tpu", False),
+    ("", False)])
+def test_cpu_requested_only_when_exactly_cpu(monkeypatch, value, want):
     from mpisppy_tpu.utils import platform
-    assert platform.enable_compile_cache_if_cpu \
-        is platform.enable_compile_cache
+    monkeypatch.setenv("JAX_PLATFORMS", value)
+    assert platform.cpu_requested() is want
+
+
+def test_compile_cache_root_defaults_to_checkout(monkeypatch):
+    from mpisppy_tpu.utils import platform
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert platform.compile_cache_root() == os.path.join(repo,
+                                                         ".jax_cache")
+    assert cc.aot_cache_dir() == os.path.join(repo, ".jax_cache", "aot")
 
 
 def test_restart_delay_shared_policy():

@@ -571,7 +571,7 @@ class TestZeroCostGuards:
         # fused jitted call
         assert "phase_timing" in src
         assert "_superstep_phased" in src
-        assert "self._superstep(" in src
+        assert "fused_superstep(solver)(" in src
 
     def test_disabled_overhead_under_two_percent(self):
         """Telemetry-off ph_iteration vs a replica of the pre-telemetry
