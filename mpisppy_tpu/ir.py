@@ -378,6 +378,13 @@ def delta_idx(batch):
 # CPU golden (chip run, PR 21).  The CPU backend ignores the flag.
 _PREC = jax.lax.Precision.HIGHEST
 
+# Scope names on the device ops of the batched matvecs and of the
+# consensus reduction (segment sums and their scatter back): stable
+# names for a profiler trace to attribute kernel time by, where fusion
+# numbers change from build to build.  Trace-time metadata only.
+MATVEC_SCOPE = "pdhg_matvec"
+CONSENSUS_SCOPE = "pdhg_consensus"
+
 
 def bmatvec(A, x):
     """Batched A @ x: A (SA, M, N) with SA == S or SA == 1 (shared
@@ -393,24 +400,28 @@ def bmatvec(A, x):
     jax.experimental.sparse's dot_general rules (the `@` below
     dispatches on the BCOO type), dropping the dense M*N FLOPs per
     scenario to nnz(shared)."""
-    if isinstance(A, SplitA):
-        out = (x @ A.shared.T if isinstance(A, SparseSplitA)
-               else jnp.matmul(x, A.shared.T, precision=_PREC))
-        return out.at[:, A.rows].add(A.vals * jnp.take(x, A.cols, axis=1))
-    if A.shape[0] == 1:
-        return jnp.matmul(x, A[0].T, precision=_PREC)
-    return jnp.einsum("smn,sn->sm", A, x, precision=_PREC)
+    with jax.named_scope(MATVEC_SCOPE):
+        if isinstance(A, SplitA):
+            out = (x @ A.shared.T if isinstance(A, SparseSplitA)
+                   else jnp.matmul(x, A.shared.T, precision=_PREC))
+            return out.at[:, A.rows].add(
+                A.vals * jnp.take(x, A.cols, axis=1))
+        if A.shape[0] == 1:
+            return jnp.matmul(x, A[0].T, precision=_PREC)
+        return jnp.einsum("smn,sn->sm", A, x, precision=_PREC)
 
 
 def bmatvec_t(A, y):
     """Batched A^T @ y: A (SA, M, N) or SplitA, y (S, M) -> (S, N)."""
-    if isinstance(A, SplitA):
-        out = (y @ A.shared if isinstance(A, SparseSplitA)
-               else jnp.matmul(y, A.shared, precision=_PREC))
-        return out.at[:, A.cols].add(A.vals * jnp.take(y, A.rows, axis=1))
-    if A.shape[0] == 1:
-        return jnp.matmul(y, A[0], precision=_PREC)
-    return jnp.einsum("smn,sm->sn", A, y, precision=_PREC)
+    with jax.named_scope(MATVEC_SCOPE):
+        if isinstance(A, SplitA):
+            out = (y @ A.shared if isinstance(A, SparseSplitA)
+                   else jnp.matmul(y, A.shared, precision=_PREC))
+            return out.at[:, A.cols].add(
+                A.vals * jnp.take(y, A.rows, axis=1))
+        if A.shape[0] == 1:
+            return jnp.matmul(y, A[0], precision=_PREC)
+        return jnp.einsum("smn,sm->sn", A, y, precision=_PREC)
 
 
 def node_segment_sum(node_of, num_nodes):
@@ -434,8 +445,9 @@ def node_segment_sum(node_of, num_nodes):
     size = num_nodes * K
 
     def segsum(v):
-        z = jnp.zeros((size,), v.dtype).at[fl].add(v.reshape(-1))
-        return z[flatid]
+        with jax.named_scope(CONSENSUS_SCOPE):
+            z = jnp.zeros((size,), v.dtype).at[fl].add(v.reshape(-1))
+            return z[flatid]
 
     return flatid, segsum
 

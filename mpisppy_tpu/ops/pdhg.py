@@ -51,9 +51,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import telemetry as _telemetry
 # one shared-A broadcast dispatch rule for the whole package: the
 # SA == 1 fast path turns the batched matvec into a real matmul
-from ..ir import SplitA
+from ..ir import CONSENSUS_SCOPE, SplitA
 from ..ir import bmatvec as _Ax
 from ..ir import bmatvec_t as _ATy
 
@@ -682,16 +683,19 @@ class PDHGSolver:
         the construction-time tolerance without recompiling — the analog
         of per-iteration solver mipgap schedules (reference
         extensions/mipgapper.py)."""
-        S, N = c.shape
-        M = prep.A.shape[1]
-        if obj_const is None:
-            obj_const = jnp.zeros((S,), c.dtype)
-        if x0 is None:
-            x0 = jnp.zeros((S, N), c.dtype)
-        if y0 is None:
-            y0 = jnp.zeros((S, M), c.dtype)
-        return self._solve_jit(prep, c, qdiag, lb, ub, obj_const, x0, y0,
-                               consensus, eps, iters_cap)
+        tel = _telemetry.get()
+        with tel.span("pdhg.args"):
+            S, N = c.shape
+            M = prep.A.shape[1]
+            if obj_const is None:
+                obj_const = jnp.zeros((S,), c.dtype)
+            if x0 is None:
+                x0 = jnp.zeros((S, N), c.dtype)
+            if y0 is None:
+                y0 = jnp.zeros((S, M), c.dtype)
+        with tel.span("pdhg.dispatch"):
+            return self._solve_jit(prep, c, qdiag, lb, ub, obj_const, x0,
+                                   y0, consensus, eps, iters_cap)
 
     def solve_compacted(self, prep: PreparedBatch, c, qdiag, lb, ub,
                         obj_const=None, x0=None, y0=None,
@@ -870,11 +874,13 @@ class PDHGSolver:
             def csum(g):
                 """Adjoint of the shared-variable broadcast: nonant
                 slots <- sum over node members, broadcast back."""
-                return g.at[:, na].set(segsum(g[:, na]))
+                with jax.named_scope(CONSENSUS_SCOPE):
+                    return g.at[:, na].set(segsum(g[:, na]))
 
             def cavg(g):
-                g2 = csum(g)
-                return g2.at[:, na].set(g2[:, na] / counts)
+                with jax.named_scope(CONSENSUS_SCOPE):
+                    g2 = csum(g)
+                    return g2.at[:, na].set(g2[:, na] / counts)
 
             # z-space norm weights: shared coords counted once
             wz = jnp.ones_like(cs).at[:, na].set(1.0 / counts)

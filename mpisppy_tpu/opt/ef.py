@@ -37,6 +37,7 @@ class ExtensiveForm(SPOpt):
             nonant_idx=b.nonant_idx,
             num_nodes=b.tree.num_nodes)
         self._result = None
+        self._solves = 0     # the solve id carried by this EF's spans
 
     def solve_extensive_form(self, solver_options=None, tee=False,
                              certify=True, x0=None, y0=None):
@@ -52,24 +53,27 @@ class ExtensiveForm(SPOpt):
         relaxation callers (models/acopf3.soc_refine's cut loop) hand
         each round the previous round's iterates, the persistent-
         solver analog."""
-        b = self.batch
-        p = b.prob[:, None]
-        res = self.solver.solve(
-            self.prep,
-            b.c * p,
-            b.qdiag * p,
-            b.lb, b.ub,
-            obj_const=b.obj_const * b.prob,
-            x0=x0, y0=y0,
-            consensus=self.consensus)
-        if certify and not bool(jnp.all(res.converged)):
-            res = self._certified_ef_resolve(res)
-        self._result = res
-        global_toc(
-            f"EF solve: obj={self.get_objective_value():.6g} "
-            f"pres={float(jnp.max(res.pres)):.2e} "
-            f"gap={float(jnp.max(res.gap)):.2e} "
-            f"iters={int(res.iters)}", tee)
+        b, tel = self.batch, self._tel
+        self._solves += 1
+        k = self._solves
+        with tel.span("ef.solve", solve=k):
+            with tel.span("ef.weights", solve=k):
+                p = b.prob[:, None]
+                c, qdiag = b.c * p, b.qdiag * p
+                obj_const = b.obj_const * b.prob
+            res = self.solver.solve(
+                self.prep, c, qdiag, b.lb, b.ub, obj_const=obj_const,
+                x0=x0, y0=y0, consensus=self.consensus)
+            with tel.span("ef.certify", solve=k):
+                if certify and not bool(jnp.all(res.converged)):
+                    res = self._certified_ef_resolve(res)
+            self._result = res
+            with tel.span("ef.log", solve=k):
+                global_toc(
+                    f"EF solve: obj={self.get_objective_value():.6g} "
+                    f"pres={float(jnp.max(res.pres)):.2e} "
+                    f"gap={float(jnp.max(res.gap)):.2e} "
+                    f"iters={int(res.iters)}", tee)
         return res
 
     def _certified_ef_resolve(self, res, c=None, qdiag=None, lb=None,

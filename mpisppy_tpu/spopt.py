@@ -246,97 +246,95 @@ class SPOpt(SPBase):
                     "(batch=...): _certified_resolve scatters into "
                     "self.batch-shaped results")
         b = self.batch if batch is None else batch
-        t0 = time.time()
         tel = self._tel
-        tn0 = time.monotonic_ns() if tel.enabled else 0
-        if batch is not None:
-            cache = (x0, y0)
-        elif isinstance(warm, str):
-            cache = self._named_warm.get(warm, (None, None))
-        else:
-            cache = (self._x_warm, self._y_warm) if warm else (None, None)
-            if x0 is not None or y0 is not None:
+        with tel.span("solve.loop"):
+            t0 = time.time()
+            if batch is not None:
                 cache = (x0, y0)
-        eps_arg = self.solver_eps if eps is None else eps
-        if prep is not None:
-            # explicit prep (streaming block solves): hot-dtype
-            # promotion does not apply — the caller chose the prep's
-            # dtype, and a promoted solver with a mismatched-dtype
-            # prep would silently recompile per call
-            solver = self.solver
-        else:
-            # hot-dtype promotion: once the requested tolerance crosses
-            # the low-precision eps floor, route this solve through the
-            # full-precision pair (monotone under the ladder/Gapper
-            # schedules, so this re-routes at most once per run)
-            solver, prep = self.active_solver_prep(eps_arg)
-        dens = self._prep_density(prep)
-        args = (prep,
-                b.c if c is None else c,
-                b.qdiag if qdiag is None else qdiag,
-                b.lb if lb is None else lb,
-                b.ub if ub is None else ub)
-        kw = dict(obj_const=b.obj_const, x0=cache[0], y0=cache[1],
-                  eps=eps_arg)
-        # compaction (opt-in via pdhg_compact_threshold) applies only
-        # to uncapped solves: an iters_cap caller is screening and owns
-        # its own budget/shape discipline
-        if solver.compact_threshold > 0.0 and iters_cap is None:
-            traj = []
-            res = solver.solve_compacted(
-                *args, **kw, probs=b.prob, on_segment=traj.append)
-            self._active_traj = traj
-            full = float(max(int(np.sum(_host(b.prob) > 0)), 1))
-            self._active_fraction = (traj[-1]["active"] / full
-                                     if traj else 0.0)
-            # FLOPs the compacted segments did NOT spend on rows the
-            # full-width solve would have carried
-            saved = sum(
-                _mfu.pdhg_flops(t["seg_iters"],
-                                b.num_scens - t["width"],
-                                b.num_rows, b.num_vars,
-                                solver.check_every, density=dens)
-                for t in traj if t["width"] < b.num_scens)
-            self._flops_saved += saved
-        else:
-            res = solver.solve(*args, **kw, iters_cap=iters_cap)
-            saved = 0.0
-            self._active_fraction = float(
-                np.sum(~_host(res.converged)
-                       & (_host(b.prob) > 0))
-                / max(int(np.sum(_host(b.prob) > 0)), 1))
-        it_n = int(_host(res.iters))
-        rst_n = int(np.sum(_host(res.restarts)))
-        # net of compaction savings: saved counts work NOT done
-        self._flops += _mfu.pdhg_flops(
-            it_n, b.num_scens, b.num_rows, b.num_vars,
-            solver.check_every, density=dens) - saved
-        if isinstance(prep.A, SparseSplitA):
-            # two shared-block products (forward + transpose) per
-            # inner iteration route through jax.experimental.sparse
-            self._sparse_matvecs += 2 * it_n
-        self._kernel_iters += it_n
-        self._restarts_total += rst_n
-        if certify:
-            select = None
-            if certify == "feas":
-                tol = 10 * float(self.solver_eps)
-                select = _host(res.pres) >= tol
-            res = self._certified_resolve(res, c, qdiag, lb, ub,
-                                          select=select)
-        if batch is not None:
-            pass  # block solves never clobber the self.batch-shaped caches
-        elif isinstance(warm, str):
-            self._named_warm[warm] = (res.x, res.y)
-        elif warm:
-            self._x_warm = res.x
-            self._y_warm = res.y
-        jax.block_until_ready(res.x)
-        dt = time.time() - t0
+            elif isinstance(warm, str):
+                cache = self._named_warm.get(warm, (None, None))
+            else:
+                cache = (self._x_warm, self._y_warm) if warm else (None, None)
+                if x0 is not None or y0 is not None:
+                    cache = (x0, y0)
+            eps_arg = self.solver_eps if eps is None else eps
+            if prep is not None:
+                # explicit prep (streaming block solves): hot-dtype
+                # promotion does not apply — the caller chose the prep's
+                # dtype, and a promoted solver with a mismatched-dtype
+                # prep would silently recompile per call
+                solver = self.solver
+            else:
+                # hot-dtype promotion: once the requested tolerance crosses
+                # the low-precision eps floor, route this solve through the
+                # full-precision pair (monotone under the ladder/Gapper
+                # schedules, so this re-routes at most once per run)
+                solver, prep = self.active_solver_prep(eps_arg)
+            dens = self._prep_density(prep)
+            args = (prep,
+                    b.c if c is None else c,
+                    b.qdiag if qdiag is None else qdiag,
+                    b.lb if lb is None else lb,
+                    b.ub if ub is None else ub)
+            kw = dict(obj_const=b.obj_const, x0=cache[0], y0=cache[1],
+                      eps=eps_arg)
+            # compaction (opt-in via pdhg_compact_threshold) applies only
+            # to uncapped solves: an iters_cap caller is screening and owns
+            # its own budget/shape discipline
+            if solver.compact_threshold > 0.0 and iters_cap is None:
+                traj = []
+                res = solver.solve_compacted(
+                    *args, **kw, probs=b.prob, on_segment=traj.append)
+                self._active_traj = traj
+                full = float(max(int(np.sum(_host(b.prob) > 0)), 1))
+                self._active_fraction = (traj[-1]["active"] / full
+                                         if traj else 0.0)
+                # FLOPs the compacted segments did NOT spend on rows the
+                # full-width solve would have carried
+                saved = sum(
+                    _mfu.pdhg_flops(t["seg_iters"],
+                                    b.num_scens - t["width"],
+                                    b.num_rows, b.num_vars,
+                                    solver.check_every, density=dens)
+                    for t in traj if t["width"] < b.num_scens)
+                self._flops_saved += saved
+            else:
+                res = solver.solve(*args, **kw, iters_cap=iters_cap)
+                saved = 0.0
+                self._active_fraction = float(
+                    np.sum(~_host(res.converged)
+                           & (_host(b.prob) > 0))
+                    / max(int(np.sum(_host(b.prob) > 0)), 1))
+            it_n = int(_host(res.iters))
+            rst_n = int(np.sum(_host(res.restarts)))
+            # net of compaction savings: saved counts work NOT done
+            self._flops += _mfu.pdhg_flops(
+                it_n, b.num_scens, b.num_rows, b.num_vars,
+                solver.check_every, density=dens) - saved
+            if isinstance(prep.A, SparseSplitA):
+                # two shared-block products (forward + transpose) per
+                # inner iteration route through jax.experimental.sparse
+                self._sparse_matvecs += 2 * it_n
+            self._kernel_iters += it_n
+            self._restarts_total += rst_n
+            if certify:
+                select = None
+                if certify == "feas":
+                    tol = 10 * float(self.solver_eps)
+                    select = _host(res.pres) >= tol
+                res = self._certified_resolve(res, c, qdiag, lb, ub,
+                                              select=select)
+            if batch is not None:
+                pass  # block solves never clobber the self.batch-shaped caches
+            elif isinstance(warm, str):
+                self._named_warm[warm] = (res.x, res.y)
+            elif warm:
+                self._x_warm = res.x
+                self._y_warm = res.y
+            jax.block_until_ready(res.x)
+            dt = time.time() - t0
         self._solve_wall += dt
         if tel.enabled:
-            tel.tracer.record_span("solve.loop", tn0,
-                                   time.monotonic_ns())
             r = tel.registry
             r.counter("solve.calls").inc()
             r.counter("solve.kernel_iters").inc(it_n)
@@ -359,8 +357,6 @@ class SPOpt(SPBase):
                         mean_cycle=it_n * b.num_scens / max(
                             rst_n + b.num_scens, 1),
                         iters=it_n)
-            _mfu.record_to_registry(r, self._flops, self._solve_wall,
-                                    kernel_iters=self._kernel_iters)
         if dtiming or self.options.get("display_timing"):
             self._solve_times.append(dt)
             global_toc(f"solve_loop: {dt*1e3:8.1f} ms, "
@@ -631,10 +627,6 @@ class SPOpt(SPBase):
         dev = jax.devices()[0]
         dt = self._kernel_dtype()
         u = _mfu.mfu(self._flops, self._solve_wall, dev, dtype=dt)
-        _mfu.record_to_registry(self._tel.registry, self._flops,
-                                self._solve_wall,
-                                kernel_iters=self._kernel_iters,
-                                device=dev, dtype=dt)
         return {
             "flops": self._flops,
             "solve_wall_s": self._solve_wall,

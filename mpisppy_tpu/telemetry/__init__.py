@@ -5,6 +5,7 @@ Three layers (doc/src/telemetry.md):
   * `tracer` — span() tracing over CLOCK_MONOTONIC into a lock-free
     ring buffer, exported as Chrome/Perfetto trace-event JSON with one
     process row per hub/spoke (export.chrome_events / merge_traces);
+    each span is also a JAX profiler annotation "sppy.<name>";
   * `metrics` — counters / gauges / time-value histograms + a bounded
     event log, snapshotted to JSONL and optionally Prometheus text;
   * this facade — ONE process-global `Telemetry` handle configured
@@ -15,8 +16,9 @@ Three layers (doc/src/telemetry.md):
 Zero-cost-when-off: a disabled handle exposes the shared NullTracer /
 null-instrument registry, so hot paths hold real references and the
 off-path cost is an attribute read and a false branch — no allocation,
-no host sync, and (structurally: this package never imports jax) no
-`block_until_ready` anywhere in the telemetry layer.
+no host sync, and (structurally: this package imports nothing of jax
+but the tracer's profiler annotation) no `block_until_ready` anywhere
+in the telemetry layer.
 
 Config forms accepted (options value or env var):
     None / False / "0"|"off"|"false"      disabled (default)
@@ -203,22 +205,6 @@ def traffic_counters(registry=None):
     vals = ({k: c.value for k, c in reg._counters.items()}
             if reg.enabled else {})
     return {n.replace(".", "_"): int(vals.get(n, 0)) for n in names}
-
-
-def pdhg_counters(registry=None):
-    """Inner-solver adaptive-work counters for bench JSON (zeros when
-    the run had telemetry off — keys are stable either way)."""
-    reg = registry if registry is not None else get().registry
-    names = ("pdhg.inner_iters_total", "pdhg.restarts_total",
-             "pdhg.flops_saved", "pdhg.promotions",
-             "pdhg.sparse_matvecs")
-    vals = ({k: c.value for k, c in reg._counters.items()}
-            if reg.enabled else {})
-    out = {n.replace(".", "_"): int(vals.get(n, 0)) for n in names}
-    g = (reg._gauges.get("pdhg.active_fraction")
-         if reg.enabled else None)
-    out["pdhg_active_fraction"] = float(g.value) if g is not None else 0.0
-    return out
 
 
 def stream_counters(registry=None):

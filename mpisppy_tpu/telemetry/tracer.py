@@ -1,10 +1,12 @@
-"""Span tracing over monotonic clocks into a lock-free ring buffer.
+"""Span tracing over monotonic clocks into a lock-free ring buffer, and
+onto the JAX profiler's timeline.
 
 Design constraints (doc/src/telemetry.md):
 
   * recording a span must be cheap enough for per-iteration hot paths:
-    one `time.monotonic_ns()` call on enter and one slot assignment on
-    exit — no locks, no allocation beyond the record tuple;
+    one `time.monotonic_ns()` call and one profiler annotation on enter,
+    one slot assignment on exit — no locks, no allocation beyond the
+    annotation and the record tuple;
   * the buffer is bounded: a preallocated slot list indexed by an
     `itertools.count()` sequence (atomic under the GIL, so concurrent
     spoke threads never tear or lose the index), with old records
@@ -12,9 +14,14 @@ Design constraints (doc/src/telemetry.md):
   * timestamps are `CLOCK_MONOTONIC` nanoseconds — system-wide on
     Linux, so traces recorded by separate spoke PROCESSES merge onto
     one consistent timeline with the hub's (export.merge_traces);
-  * NEVER imports jax: a tracer call can therefore never introduce a
-    device sync into the jitted path (tests/test_telemetry.py guards
-    this structurally).
+  * every span is also a `jax.profiler.TraceAnnotation` named
+    "sppy.<name>" (its args as the annotation's arguments), so under
+    `jax.profiler.start_trace` it lands on the profiler's host plane, on
+    the clock of the device ops;
+  * imports nothing of jax but that one class, on the first span: an
+    annotation cannot sync the device, so a tracer call can never
+    introduce a device sync into the jitted path (tests/test_telemetry.py
+    guards this structurally).
 
 Records are tuples (kind first):
     ("X", name, pid, tid, ts_us, dur_us, args)   complete span
@@ -35,9 +42,10 @@ import os
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, inside
+    a profiler annotation "sppy.<name>"."""
 
-    __slots__ = ("_tracer", "_name", "_pid", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_pid", "_args", "_t0", "_ann")
 
     def __init__(self, tracer, name, pid, args):
         self._tracer = tracer
@@ -46,11 +54,16 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation("sppy." + self._name,
+                                    **(self._args or {}))
+        self._ann.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.monotonic_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         self._tracer._append(
             ("X", self._name, self._pid, threading.get_native_id(),
              self._t0 // 1000, (t1 - self._t0) // 1000, self._args))
@@ -151,13 +164,6 @@ class Tracer:
         self._append(("C", name, self._track_pid(track),
                       time.monotonic_ns() // 1000, dict(values)))
 
-    def record_span(self, name, t0_ns, t1_ns, track=None, args=None):
-        """Record an already-measured interval (callers that timed the
-        work themselves, e.g. solve_loop's existing wall accounting)."""
-        self._append(("X", name, self._track_pid(track),
-                      threading.get_native_id(), t0_ns // 1000,
-                      max(t1_ns - t0_ns, 0) // 1000, args))
-
     # -- drain ------------------------------------------------------------
     def records(self):
         """Snapshot of retained records in emission order."""
@@ -199,9 +205,6 @@ class NullTracer:
         pass
 
     def counter(self, name, values, track=None):
-        pass
-
-    def record_span(self, name, t0_ns, t1_ns, track=None, args=None):
         pass
 
     def records(self):

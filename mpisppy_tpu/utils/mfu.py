@@ -124,23 +124,3 @@ def mfu(flops, wall_seconds, device=None, dtype="float32"):
         return None
     return flops / wall_seconds / peak
 
-
-def record_to_registry(registry, flops, wall_seconds, kernel_iters=None,
-                       device=None, dtype="float32"):
-    """Mirror the accumulated FLOP/wall/MFU numbers into the telemetry
-    registry as gauges, so hardware utilization shows up in metrics
-    snapshots (telemetry/metrics.py write_jsonl) and not only in
-    bench.py's final JSON.  No-op on a disabled registry — callers may
-    invoke it unconditionally from hot paths."""
-    if not getattr(registry, "enabled", False):
-        return
-    registry.gauge("mfu.kernel_flops").set(flops)
-    registry.gauge("mfu.solve_wall_seconds").set(wall_seconds)
-    if kernel_iters is not None:
-        registry.gauge("mfu.kernel_iters").set(kernel_iters)
-        if wall_seconds > 0:
-            registry.gauge("mfu.iters_per_sec").set(
-                kernel_iters / wall_seconds)
-    u = mfu(flops, wall_seconds, device, dtype)
-    if u is not None:
-        registry.gauge("mfu.mfu").set(u)
