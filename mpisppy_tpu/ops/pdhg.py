@@ -54,7 +54,7 @@ from jax import lax
 from .. import telemetry as _telemetry
 # one shared-A broadcast dispatch rule for the whole package: the
 # SA == 1 fast path turns the batched matvec into a real matmul
-from ..ir import CONSENSUS_SCOPE, SplitA
+from ..ir import CONSENSUS_SCOPE, SplitA, segment_total
 from ..ir import bmatvec as _Ax
 from ..ir import bmatvec_t as _ATy
 
@@ -131,6 +131,31 @@ class ConsensusSpec:
 
 _register(ConsensusSpec, ("node_of", "nonant_idx"),
           ("num_nodes", "num_copies"))
+
+
+def consensus_reduction(consensus: ConsensusSpec, shape, dtype):
+    """(csum, cavg, wz) of a ConsensusSpec over (S, N) arrays.
+
+    csum(g): the adjoint of the shared-variable broadcast — each nonant
+    slot <- the sum over its node's members, other columns as they are
+    (`ir.segment_total`: dense, with its node map built once, here,
+    outside the PDHG loop, so the loop body holds no scatter and no
+    gather; by scatter above `ir.DENSE_SEGMENT_MAX`); cavg(g): csum
+    over the member count; wz: z-space norm weights (a shared
+    coordinate counted once).  Counts the form in the telemetry
+    counter pdhg.consensus_dense / pdhg.consensus_scatter, once per
+    trace under jit."""
+    csum, form = segment_total(consensus.node_of, consensus.num_nodes,
+                               consensus.nonant_idx, shape[1])
+    _telemetry.get().counter(f"pdhg.consensus_{form}").inc()
+    # the member count in nonant columns, 1 elsewhere
+    counts = csum(jnp.ones(shape, dtype))
+
+    def cavg(g):
+        with jax.named_scope(CONSENSUS_SCOPE):
+            return csum(g) / counts
+
+    return csum, cavg, 1.0 / counts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -865,25 +890,8 @@ class PDHGSolver:
             eps = eps_out.astype(cs.dtype)
 
         if consensus is not None:
-            from ..ir import node_segment_sum
-            na = consensus.nonant_idx
-            _, segsum = node_segment_sum(consensus.node_of,
-                                         consensus.num_nodes)
-            counts = segsum(jnp.ones_like(cs[:, na]))
-
-            def csum(g):
-                """Adjoint of the shared-variable broadcast: nonant
-                slots <- sum over node members, broadcast back."""
-                with jax.named_scope(CONSENSUS_SCOPE):
-                    return g.at[:, na].set(segsum(g[:, na]))
-
-            def cavg(g):
-                with jax.named_scope(CONSENSUS_SCOPE):
-                    g2 = csum(g)
-                    return g2.at[:, na].set(g2[:, na] / counts)
-
-            # z-space norm weights: shared coords counted once
-            wz = jnp.ones_like(cs).at[:, na].set(1.0 / counts)
+            csum, cavg, wz = consensus_reduction(consensus, cs.shape,
+                                                 cs.dtype)
 
             # per-copy reductions over the scenario axis: with
             # num_copies stacked independent EFs (opt/mip._lp_multi),
