@@ -197,11 +197,13 @@ class SplitA:
     touches only a few coordinates per scenario (farmer: the per-crop
     yield coefficients — 2*n_crops entries out of M*N).  The batched
     matvec then runs as ONE (S, N) x (N, M) matmul on the MXU plus an
-    nnz-sized scatter, instead of an (S, M, N) batched GEMV: per-
-    iteration HBM traffic drops from S*M*N to M*N + S*nnz — the same
-    trick as ScenarioBatch.shared_A (row-bound uncertainty), extended
-    to matrix uncertainty.  `shared` stores ZEROS at the delta
-    positions, so the scatter ADD needs no masking.
+    (S, nnz) multiply-add of the delta, instead of an (S, M, N) batched
+    GEMV: per-iteration HBM traffic drops from S*M*N to M*N + S*nnz —
+    the same trick as ScenarioBatch.shared_A (row-bound uncertainty),
+    extended to matrix uncertainty.  `shared` stores ZEROS at the delta
+    positions, so the delta's ADD needs no masking.  With `runs`
+    attached (`with_runs`) the delta is multiplied by static diagonal
+    slices; without them by a gather and a scatter-add.
 
     Models declare the delta coordinate set via
     model_meta["A_delta_idx"] = (rows, cols); SPOpt then builds the
@@ -214,6 +216,9 @@ class SplitA:
     rows: Any     # (nnz,) int32 row of each per-scenario entry
     cols: Any     # (nnz,) int32 column of each per-scenario entry
     vals: Any     # (S, nnz) per-scenario values at (rows, cols)
+    # static: the delta's diagonal runs (delta_runs), or None for the
+    # gather / scatter-add form; attached by with_runs
+    runs: Any = None
 
     @property
     def shape(self):
@@ -247,9 +252,21 @@ class SplitA:
         data in SparseSplitA)."""
         return self.shared * row_mult[:, None] * col_mult[None, :]
 
+    def with_runs(self):
+        """This operator with its delta's static runs (`delta_runs`)
+        attached, so `bmatvec` multiplies the delta by static slices:
+        itself when it carries runs already, or when its coordinates
+        are traced or have no short run form.  Subclass-preserving."""
+        if self.runs is not None:
+            return self
+        runs = delta_runs(self.rows, self.cols, *self.shared.shape)
+        if runs is None:
+            return self
+        return dataclasses.replace(self, runs=runs)
+
 
 _register(SplitA, data_fields=("shared", "rows", "cols", "vals"),
-          meta_fields=())
+          meta_fields=("runs",))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -263,8 +280,8 @@ class SparseSplitA(SplitA):
     for mostly-zero entries.  Storing the shared block as BCOO routes
     `bmatvec`/`bmatvec_t` through the sparse dot_general rules, so the
     per-iteration cost drops from O(S*M*N) to O(S*nnz(shared) +
-    S*nnz(delta)).  The per-scenario delta stays in (rows, cols, vals)
-    scatter form, identical to SplitA — gather/compaction
+    S*nnz(delta)).  The per-scenario delta keeps SplitA's (rows, cols,
+    vals) form and its runs — gather/compaction
     (`ops/pdhg._gather_prep`, `solve_compacted`) and scenario padding
     touch only `vals` and work unchanged.
 
@@ -300,7 +317,38 @@ class SparseSplitA(SplitA):
 
 
 _register(SparseSplitA, data_fields=("shared", "rows", "cols", "vals"),
-          meta_fields=())
+          meta_fields=("runs",))
+
+
+# Most diagonal runs a SplitA's delta is multiplied by as static
+# slices.  Each run is one slice, multiply and pad-add that the
+# compiler unrolls into the loop body, so the body grows with the
+# count; past it the delta keeps its gather and scatter-add, whose size
+# does not.  Farmer's delta is 2 runs, 2 per member when bundled.
+DELTA_RUNS_MAX = 16
+
+
+def delta_runs(rows, cols, m, n):
+    """The diagonal runs of a delta coordinate set in an (m, n) matrix:
+    maximal stretches of consecutive entries k with rows[k+1] ==
+    rows[k] + 1 and cols[k+1] == cols[k] + 1, as a tuple of static
+    (k0, r0, c0, length).  None when the coordinates are traced, lie
+    outside the matrix, or break into more than DELTA_RUNS_MAX runs."""
+    if isinstance(rows, jax.core.Tracer) or isinstance(cols,
+                                                       jax.core.Tracer):
+        return None
+    r = np.asarray(rows, np.int64).ravel()
+    c = np.asarray(cols, np.int64).ravel()
+    if r.size and (r.min() < 0 or r.max() >= m
+                   or c.min() < 0 or c.max() >= n):
+        return None
+    brk = np.flatnonzero((np.diff(r) != 1) | (np.diff(c) != 1)) + 1
+    starts = np.concatenate([[0], brk]) if r.size else brk
+    if starts.size > DELTA_RUNS_MAX:
+        return None
+    ends = np.append(starts[1:], r.size)
+    return tuple((int(k0), int(r[k0]), int(c[k0]), int(k1 - k0))
+                 for k0, k1 in zip(starts, ends))
 
 
 def shared_density(A):
@@ -331,7 +379,7 @@ def sparsify_split(A, threshold):
     nse = max(int(np.count_nonzero(sh)), 1)
     bcoo = jsparse.BCOO.fromdense(jnp.asarray(A.shared), nse=nse)
     return SparseSplitA(shared=bcoo, rows=A.rows, cols=A.cols,
-                        vals=A.vals)
+                        vals=A.vals, runs=A.runs)
 
 
 class Static:
@@ -379,11 +427,11 @@ def delta_idx(batch):
 _PREC = jax.lax.Precision.HIGHEST
 
 # Scope names on the device ops of the batched matvecs, of a SplitA's
-# per-scenario delta (its gather and scatter-add, nested under the
-# matvec's scope) and of the consensus reduction (segment sums and their
-# scatter back): stable names for a profiler trace to attribute kernel
-# time by, where fusion numbers change from build to build.  Trace-time
-# metadata only.
+# per-scenario delta (`_add_delta`, nested under the matvec's scope)
+# and of the consensus reduction (segment sums and their scatter
+# back): stable names for a profiler trace to attribute kernel time by,
+# where fusion numbers change from build to build.  Trace-time metadata
+# only.
 MATVEC_SCOPE = "pdhg_matvec"
 SPLIT_DELTA_SCOPE = "pdhg_split_delta"
 CONSENSUS_SCOPE = "pdhg_consensus"
@@ -400,6 +448,36 @@ def matvec_form(A):
     return "shared" if A.shape[0] == 1 else "dense"
 
 
+def delta_form(A):
+    """How `bmatvec` multiplies a SplitA's delta: "runs" (static
+    diagonal slices) or "scatter" (gather and scatter-add); None for an
+    operator with no delta."""
+    if not isinstance(A, SplitA):
+        return None
+    return "scatter" if A.runs is None else "runs"
+
+
+def _add_delta(out, A, v, transpose):
+    """out + the SplitA delta's product with v (A x, or A^T y with
+    transpose), under SPLIT_DELTA_SCOPE.  With runs, each run adds
+    vals[:, k0:k0+L] * v[:, a:a+L] into out[:, b:b+L] as static slices
+    padded to out's width, so the compiler sees no gather or scatter and
+    can fuse the add into the ops around it; runs onto the same rows or
+    columns add in turn."""
+    with jax.named_scope(SPLIT_DELTA_SCOPE):
+        if A.runs is None:
+            src, dst = (A.rows, A.cols) if transpose else (A.cols, A.rows)
+            return out.at[:, dst].add(A.vals * jnp.take(v, src, axis=1))
+        width = out.shape[1]
+        zero = jnp.zeros((), out.dtype)
+        for k0, r0, c0, n in A.runs:
+            a, b = (r0, c0) if transpose else (c0, r0)
+            prod = (A.vals[:, k0:k0 + n] * v[:, a:a + n]).astype(out.dtype)
+            out = out + jax.lax.pad(prod, zero,
+                                    ((0, 0, 0), (b, width - b - n, 0)))
+        return out
+
+
 def bmatvec(A, x):
     """Batched A @ x: A (SA, M, N) with SA == S or SA == 1 (shared
     constraint matrix), or a SplitA; x (S, N) -> (S, M).
@@ -409,18 +487,16 @@ def bmatvec(A, x):
     two-stage demand models): one (M, N) matrix turns the batched
     matvec into a real (S, N) x (N, M) matmul on the MXU and cuts the
     constraint-tensor memory by S.  SplitA extends the same fast path
-    to sparse MATRIX uncertainty (shared matmul + nnz scatter).  With
-    a SparseSplitA the shared product routes through
-    jax.experimental.sparse's dot_general rules (the `@` below
-    dispatches on the BCOO type), dropping the dense M*N FLOPs per
-    scenario to nnz(shared)."""
+    to sparse MATRIX uncertainty (shared matmul + the delta's
+    multiply-add, `_add_delta`).  With a SparseSplitA the shared
+    product routes through jax.experimental.sparse's dot_general rules
+    (the `@` below dispatches on the BCOO type), dropping the dense M*N
+    FLOPs per scenario to nnz(shared)."""
     with jax.named_scope(MATVEC_SCOPE):
         if isinstance(A, SplitA):
             out = (x @ A.shared.T if isinstance(A, SparseSplitA)
                    else jnp.matmul(x, A.shared.T, precision=_PREC))
-            with jax.named_scope(SPLIT_DELTA_SCOPE):
-                return out.at[:, A.rows].add(
-                    A.vals * jnp.take(x, A.cols, axis=1))
+            return _add_delta(out, A, x, transpose=False)
         if A.shape[0] == 1:
             return jnp.matmul(x, A[0].T, precision=_PREC)
         return jnp.einsum("smn,sn->sm", A, x, precision=_PREC)
@@ -432,9 +508,7 @@ def bmatvec_t(A, y):
         if isinstance(A, SplitA):
             out = (y @ A.shared if isinstance(A, SparseSplitA)
                    else jnp.matmul(y, A.shared, precision=_PREC))
-            with jax.named_scope(SPLIT_DELTA_SCOPE):
-                return out.at[:, A.cols].add(
-                    A.vals * jnp.take(y, A.rows, axis=1))
+            return _add_delta(out, A, y, transpose=True)
         if A.shape[0] == 1:
             return jnp.matmul(y, A[0], precision=_PREC)
         return jnp.einsum("smn,sm->sn", A, y, precision=_PREC)

@@ -125,7 +125,8 @@ def scenario_block(indices, crops_multiplier=1, use_integer=False,
         # the yield slots (r x iac, r2 x iac) stay ZERO in shared; the
         # per-scenario values live in vals at (delta_rows, delta_cols)
         A = SplitA(shared=shared, rows=delta_rows, cols=delta_cols,
-                   vals=np.concatenate([yields, -yields], axis=1))
+                   vals=np.concatenate([yields, -yields],
+                                       axis=1)).with_runs()
     else:
         A = np.zeros((S, M, N), dtype=dtype)
         A[:, r, iac] = yields

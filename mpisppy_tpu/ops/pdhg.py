@@ -54,8 +54,8 @@ from jax import lax
 from .. import telemetry as _telemetry
 # one shared-A broadcast dispatch rule for the whole package: the
 # SA == 1 fast path turns the batched matvec into a real matmul
-from ..ir import (CONSENSUS_SCOPE, SparseSplitA, SplitA, matvec_form,
-                  segment_total)
+from ..ir import (CONSENSUS_SCOPE, SparseSplitA, SplitA, delta_form,
+                  matvec_form, segment_total)
 from ..ir import bmatvec as _Ax
 from ..ir import bmatvec_t as _ATy
 
@@ -247,8 +247,9 @@ def prepare_batch(A, row_lo, row_hi, ruiz_iters=10, shared_cols=False):
     )
 
 
-@partial(jax.jit, static_argnames=("ruiz_iters",))
-def prepare_batch_split(A, rows, cols, row_lo, row_hi, ruiz_iters=10):
+@partial(jax.jit, static_argnames=("ruiz_iters", "runs"))
+def prepare_batch_split(A, rows, cols, row_lo, row_hi, ruiz_iters=10,
+                        runs=None):
     """prepare_batch for a batch whose matrix uncertainty is confined
     to the (rows, cols) coordinate set (ir.SplitA): A is the DENSE
     (S, M, N) tensor the model built; every entry OUTSIDE the delta set
@@ -263,29 +264,34 @@ def prepare_batch_split(A, rows, cols, row_lo, row_hi, ruiz_iters=10):
     requirement (prepare_batch(shared_cols=True)) for free.
 
     Returns a PreparedBatch whose A is a SplitA and whose d_row/d_col
-    are (1, M)/(1, N) — the shared-A broadcasting convention.
+    are (1, M)/(1, N) — the shared-A broadcasting convention.  `runs`:
+    ir.delta_runs of (rows, cols), which are traced here, so the caller
+    derives them from the concrete coordinates (None: the delta keeps
+    its gather and scatter-add).
     """
     rows = jnp.asarray(rows, jnp.int32)
     cols = jnp.asarray(cols, jnp.int32)
     vals = A[:, rows, cols]                          # (S, nnz)
     A0 = A[0].at[rows, cols].set(0.0)                # (M, N) shared part
     return _prepare_split_core(A0, rows, cols, vals, row_lo, row_hi,
-                               ruiz_iters=ruiz_iters)
+                               ruiz_iters=ruiz_iters, runs=runs)
 
 
 def prepare_split_native(A: "SplitA", row_lo, row_hi, ruiz_iters=10):
     """prepare_batch_split for a batch born split (ir.ScenarioBatch.A
     IS a SplitA — the only representation at sizes where the dense
-    (S, M, N) tensor cannot exist, e.g. true-size farmer)."""
+    (S, M, N) tensor cannot exist, e.g. true-size farmer).  The prep's
+    operator carries A's delta runs, or those of its coordinates where
+    they are concrete (ir.SplitA.with_runs)."""
     return _prepare_split_core(
         A.shared, jnp.asarray(A.rows, jnp.int32),
         jnp.asarray(A.cols, jnp.int32), A.vals, row_lo, row_hi,
-        ruiz_iters=ruiz_iters)
+        ruiz_iters=ruiz_iters, runs=A.with_runs().runs)
 
 
-@partial(jax.jit, static_argnames=("ruiz_iters",))
+@partial(jax.jit, static_argnames=("ruiz_iters", "runs"))
 def _prepare_split_core(A0, rows, cols, vals, row_lo, row_hi,
-                        ruiz_iters=10):
+                        ruiz_iters=10, runs=None):
     M, N = A0.shape
     A0 = A0.at[rows, cols].set(0.0)   # enforce the zeros-at-delta contract
     eps = 1e-12
@@ -306,7 +312,7 @@ def _prepare_split_core(A0, rows, cols, vals, row_lo, row_hi,
     A0s, vs, dr, dc = lax.fori_loop(
         0, ruiz_iters, body,
         (A0, vals, jnp.ones((M,), A0.dtype), jnp.ones((N,), A0.dtype)))
-    As = SplitA(shared=A0s, rows=rows, cols=cols, vals=vs)
+    As = SplitA(shared=A0s, rows=rows, cols=cols, vals=vs, runs=runs)
     anorm = _power_iteration(As)
     d_row = dr[None, :]
     d_col = dc[None, :]
@@ -969,9 +975,12 @@ class PDHGSolver:
     # -- impl --------------------------------------------------------
     def _solve_impl(self, prep, c, qdiag, lb, ub, obj_const, x0, y0,
                     consensus=None, eps=None, iters_cap=None):
-        # the operator's form, once per trace (ir.matvec_form)
-        _telemetry.get().counter(
-            f"pdhg.matvec_{matvec_form(prep.A)}").inc()
+        # the operator's form and its delta's, once per trace
+        # (ir.matvec_form, ir.delta_form)
+        tel = _telemetry.get()
+        tel.counter(f"pdhg.matvec_{matvec_form(prep.A)}").inc()
+        if delta_form(prep.A) is not None:
+            tel.counter(f"pdhg.split_delta_{delta_form(prep.A)}").inc()
         dc, dr = prep.d_col, prep.d_row
         # scale into solver space (in the caller's precision — the
         # promotion rules of c * dc fix the OUTPUT dtype below)

@@ -23,7 +23,7 @@ import numpy as np
 
 from . import global_toc
 from . import telemetry as _telemetry
-from .ir import (SparseSplitA, SplitA, bmatvec, delta_idx,
+from .ir import (SparseSplitA, SplitA, bmatvec, delta_idx, delta_runs,
                  shared_density, sparsify_split)
 from .ops.pdhg import (PDHGSolver, PreparedBatch, prepare_batch,
                        prepare_batch_split, prepare_split_native)
@@ -143,7 +143,8 @@ class SPOpt(SPBase):
             # columns satisfy _shared_cols implicitly
             prep = prepare_batch_split(
                 A, jnp.asarray(delta[0], jnp.int32),
-                jnp.asarray(delta[1], jnp.int32), row_lo, row_hi)
+                jnp.asarray(delta[1], jnp.int32), row_lo, row_hi,
+                runs=delta_runs(delta[0], delta[1], *b.A.shape[1:]))
         else:
             prep = prepare_batch(A, row_lo, row_hi,
                                  shared_cols=self._shared_cols)
@@ -482,10 +483,8 @@ class SPOpt(SPBase):
             if isinstance(full.A, SplitA):
                 # only the per-scenario delta values gather; the shared
                 # matrix and coordinates serve every straggler as-is
-                sub_A = SplitA(shared=put(full.A.shared),
-                               rows=put(full.A.rows),
-                               cols=put(full.A.cols),
-                               vals=put(full.A.vals[idx]))
+                sub_A = jax.tree.map(put, dataclasses.replace(
+                    full.A, vals=full.A.vals[idx]))
                 prep64 = PreparedBatch(
                     A=sub_A,
                     row_lo=put(take(full.row_lo)),
@@ -778,7 +777,7 @@ class SPOpt(SPBase):
                 shared=jnp.asarray(b.A.shared)[:, nai],   # (M, Kf)
                 rows=jnp.asarray(b.A.rows, jnp.int32),
                 cols=jnp.asarray(pos_of[cols_np], jnp.int32),
-                vals=b.A.vals)
+                vals=b.A.vals).with_runs()
             A_red = jnp.asarray(b.A.shared)[None].at[:, :, nai].set(0.0)
         elif (delta is not None and not b.shared_A
                 and not self.options.get("no_split_prep")
@@ -919,8 +918,7 @@ class SPOpt(SPBase):
                 # candidate as-is; per-scenario leaves tile k-fold.
                 # A SplitA tiles its per-scenario delta values only
                 if isinstance(a, SplitA):
-                    return SplitA(shared=a.shared, rows=a.rows,
-                                  cols=a.cols, vals=tile(a.vals))
+                    return dataclasses.replace(a, vals=tile(a.vals))
                 if a.shape[0] == 1 and S_all > 1:
                     return a
                 return jnp.tile(a, (k,) + (1,) * (a.ndim - 1))

@@ -160,7 +160,7 @@ def _batch_from_payload(z):
         A = SplitA(shared=np.asarray(z["A_shared"]),
                    rows=np.asarray(z["A_rows"]),
                    cols=np.asarray(z["A_cols"]),
-                   vals=np.asarray(z["A_vals"]))
+                   vals=np.asarray(z["A_vals"])).with_runs()
     else:
         A = np.asarray(z["A"])
     tree = TreeInfo(

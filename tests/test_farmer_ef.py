@@ -17,11 +17,13 @@ from scipy.optimize import linprog
 from scipy.sparse import coo_matrix, vstack
 
 from mpisppy_tpu import telemetry
-from mpisppy_tpu.ir import SplitA
+from mpisppy_tpu.ir import SplitA, pad_scenarios
 from mpisppy_tpu.models import aircond, farmer, hydro, sslp
-from mpisppy_tpu.ops.pdhg import (_dense_row_factors, prepare_split_native,
-                                  shared_solve_jit)
+from mpisppy_tpu.ops.pdhg import (_dense_row_factors, _gather_prep,
+                                  prepare_split_native, shared_solve_jit)
 from mpisppy_tpu.opt.ef import ExtensiveForm
+from mpisppy_tpu.opt.ph import PH
+from mpisppy_tpu.phbase import fused_superstep
 from mpisppy_tpu.parallel.mesh import ScenarioMesh
 
 F32 = np.float32
@@ -193,6 +195,25 @@ def _ops(op):
                 yield from _ops(o.operation)
 
 
+def _split_delta_ops(module):
+    """(name, location) of each op under the split operator's delta
+    scope inside the lowered module's while body."""
+    return [(op.name, str(op.location)) for op in _ops(module.operation)
+            if re.search(r"\bwhile/body/(.*/)?pdhg_matvec/pdhg_split_delta/",
+                         str(op.location))]
+
+
+def _assert_delta_runs_as_slices(module):
+    # the scope is still on the delta's ops, a multiply-add of static
+    # slices: no scatter-add, and no gather (jnp.take's own function)
+    delta = _split_delta_ops(module)
+    assert any(name == "stablehlo.multiply" for name, _ in delta)
+    assert any(name == "stablehlo.slice" for name, _ in delta)
+    assert not [(name, loc) for name, loc in delta
+                if name in ("stablehlo.scatter", "stablehlo.gather")
+                or re.search(r"gather|scatter|_take\b", loc)]
+
+
 def test_split_solve_names_its_delta_and_its_form():
     b = _farmer(4, mult=1)
     ef = _ef(b, 1e-4)
@@ -201,13 +222,27 @@ def test_split_solve_names_its_delta_and_its_form():
         ef.prep, b.c, b.qdiag, b.lb, b.ub, jnp.zeros(n_s, F32),
         jnp.zeros((n_s, n), F32), jnp.zeros((n_s, m), F32), ef.consensus,
         None, None).compiler_ir("stablehlo")
-    body = [(op.name, str(op.location)) for op in _ops(module.operation)
-            if "/while/body/" in str(op.location)]
-    # the delta's gather (a call of jnp.take's own function) and its
-    # scatter-add sit under the matvec's scope
-    delta = [(name, loc) for name, loc in body
-             if re.search(r"\bpdhg_matvec/pdhg_split_delta/", loc)]
-    assert any(name == "stablehlo.scatter" for name, _ in delta)
-    assert any(re.search(r"gather|_take\b", loc) for _, loc in delta)
+    _assert_delta_runs_as_slices(module)
     assert _counter("pdhg.matvec_split") == 1
     assert _counter("pdhg.matvec_dense") == 0
+    assert _counter("pdhg.split_delta_runs") == 1
+    assert _counter("pdhg.split_delta_scatter") == 0
+
+
+def test_ph_superstep_delta_keeps_its_runs_when_padded_and_compacted():
+    # a PH superstep on a padded farmer batch, with its prep gathered
+    # as compaction gathers it: each rebuilt SplitA carries the runs
+    b = pad_scenarios(_farmer(3, mult=1), 4)
+    ph = PH({"defaultPHrho": 1.0, "PHIterLimit": 1, "convthresh": 0.0,
+             "pdhg_eps": 1e-4, "pdhg_max_iters": 400},
+            list(b.tree.scen_names), batch=b,
+            mesh=ScenarioMesh(devices=jax.devices()[:1]))
+    ph.Iter0()
+    prep = _gather_prep(ph.prep, jnp.asarray([2, 0, 3, 1], jnp.int32))
+    assert b.A.runs is not None and prep.A.runs == b.A.runs
+    module = fused_superstep(ph.solver).lower(
+        ph.state, ph.rho, ph.W_on, ph.prox_on, ph.lb_eff, ph.ub_eff,
+        ph.superstep_eps, prep, ph.batch).compiler_ir("stablehlo")
+    _assert_delta_runs_as_slices(module)
+    assert _counter("pdhg.split_delta_runs") >= 1
+    assert _counter("pdhg.split_delta_scatter") == 0

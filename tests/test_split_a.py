@@ -8,12 +8,17 @@ these tests pin that declaring model_meta["A_delta_idx"] changes no
 numbers, only the kernel's memory traffic.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from mpisppy_tpu.ir import SplitA, bmatvec, bmatvec_t
+from mpisppy_tpu import telemetry
+from mpisppy_tpu.ir import (DELTA_RUNS_MAX, SplitA, bmatvec, bmatvec_t,
+                            delta_form, delta_runs)
 from mpisppy_tpu.models import farmer
 from mpisppy_tpu.opt.ph import PH
 from mpisppy_tpu.ops.pdhg import (PDHGSolver, prepare_batch,
@@ -169,3 +174,101 @@ def test_bundled_delta_remap():
     assert isinstance(ph.prep.A, SplitA)
     ph.Iter0()
     assert np.isfinite(ph.trivial_bound)
+
+
+def _coords(case):
+    """(M, N, rows, cols) of a delta coordinate set."""
+    if case.startswith("farmer"):
+        b = farmer.build_batch(2, crops_multiplier=int(case[6:]))
+        rows, cols = (np.asarray(v) for v in b.model_meta["A_delta_idx"])
+        return b.num_rows, b.num_vars, rows, cols
+    if case == "length1":
+        return 9, 11, np.array([0, 5, 2, 7]), np.array([3, 1, 8, 0])
+    if case == "same_rows":     # two runs add into rows 1..5
+        return 9, 14, np.r_[1:6, 1:6], np.r_[0:5, 7:12]
+    if case == "same_cols":     # two runs add into columns 2..6
+        return 14, 9, np.r_[0:5, 7:12], np.r_[2:7, 2:7]
+    rng = np.random.RandomState(1)     # "shuffled": past the cap
+    k = rng.permutation(40)
+    return 20, 30, np.r_[0:20, 0:20][k], np.r_[0:20, 10:30][k]
+
+
+@pytest.mark.parametrize("case", ["farmer2", "farmer5", "length1",
+                                  "same_rows", "same_cols", "shuffled",
+                                  "traced"])
+def test_delta_runs_match_dense_and_scatter(case):
+    """The static-run form of the delta (ir.SplitA.with_runs) gives the
+    products of the dense operator and of the gather / scatter-add form;
+    coordinates past ir.DELTA_RUNS_MAX runs, or traced ones, keep the
+    scatter form."""
+    M, N, rows, cols = _coords("farmer2" if case == "traced" else case)
+    S = 3
+    rng = np.random.RandomState(0)
+    shared = rng.randn(M, N)
+    shared[rows, cols] = 0.0
+    A = SplitA(shared=jnp.asarray(shared), rows=jnp.asarray(rows, jnp.int32),
+               cols=jnp.asarray(cols, jnp.int32),
+               vals=jnp.asarray(rng.randn(S, rows.size)))
+    x = jnp.asarray(rng.randn(S, N))
+    y = jnp.asarray(rng.randn(S, M))
+    dense = np.asarray(A.to_dense())
+    want = (np.einsum("smn,sn->sm", dense, np.asarray(x)),
+            np.einsum("smn,sm->sn", dense, np.asarray(y)))
+    scatter = (bmatvec(A, x), bmatvec_t(A, y))
+    if case == "traced":
+        @jax.jit
+        def products(rows, cols):
+            At = dataclasses.replace(A, rows=rows, cols=cols).with_runs()
+            assert At.runs is None and delta_form(At) == "scatter"
+            return bmatvec(At, x), bmatvec_t(At, y)
+        got = products(A.rows, A.cols)
+    else:
+        Ar = A.with_runs()
+        n_runs = len(set(zip(rows - np.arange(rows.size),
+                             cols - np.arange(cols.size))))
+        if case == "shuffled":
+            assert n_runs > DELTA_RUNS_MAX
+            assert Ar is A and delta_form(Ar) == "scatter"
+        else:
+            assert len(Ar.runs) == n_runs <= DELTA_RUNS_MAX
+            assert delta_form(Ar) == "runs"
+        got = (bmatvec(Ar, x), bmatvec_t(Ar, y))
+    for g, w, s in zip(got, want, scatter):
+        np.testing.assert_allclose(np.asarray(g), w, rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(s), rtol=1e-12,
+                                   atol=1e-12)
+
+
+def test_farmer_delta_is_two_runs():
+    b = farmer.build_batch(4, crops_multiplier=3, split=True)
+    nc = 9
+    assert b.A.runs == ((0, 0, 0, nc), (nc, nc, 0, nc))
+    rows, cols = b.model_meta["A_delta_idx"]
+    assert delta_runs(rows, cols, b.num_rows, b.num_vars) == b.A.runs
+    # out of the matrix: no run form
+    assert delta_runs(rows, cols, int(np.max(rows)), b.num_vars) is None
+
+
+@pytest.mark.parametrize("runs", [True, False], ids=["runs", "scatter"])
+def test_solve_counts_its_delta_form(runs):
+    """pdhg.split_delta_runs / pdhg.split_delta_scatter: the delta form
+    each solve was traced with, once per trace; a PH on the dense
+    farmer batch attaches the runs of its declared coordinates."""
+    telemetry.configure({"enabled": True, "phase_timing": False})
+    try:
+        b = farmer.build_batch(9, crops_multiplier=1)
+        ph = PH({"defaultPHrho": 1.0, "PHIterLimit": 1, "convthresh": 0.0,
+                 "pdhg_max_iters": 123 if runs else 124},
+                [f"scen{i}" for i in range(9)], batch=b)
+        prep = ph.prep
+        assert prep.A.runs is not None
+        if not runs:
+            prep = dataclasses.replace(
+                prep, A=dataclasses.replace(prep.A, runs=None))
+        pb = ph.batch
+        ph.solver.solve(prep, pb.c, pb.qdiag, pb.lb, pb.ub)
+        reg = telemetry.get().registry
+        assert reg.counter("pdhg.split_delta_runs").value == int(runs)
+        assert reg.counter("pdhg.split_delta_scatter").value == int(not runs)
+    finally:
+        telemetry.reset()
