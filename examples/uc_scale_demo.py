@@ -72,19 +72,15 @@ def main(args=None):
 
     # second outer bound: the consensus-EF LP's dual objective (valid
     # at any iterate — all boxes finite) is far tighter than the
-    # W-path Lagrangian at small iteration counts; same protocol as
-    # bench.py worker_uc
+    # W-path Lagrangian at small iteration counts
     from mpisppy_tpu.opt.ef import ef_dual_bound
     ef_b, _ = ef_dual_bound(b, [f"s{i}" for i in range(S)])
     outer = max(outer, ef_b)
-    stats = ph.solve_stats()
     gap = abs(inner - outer) / max(abs(inner), 1e-9)
     print(f"incumbent (integer commitment) = {inner:.6g}")
     print(f"valid outer bound              = {outer:.6g}")
     print(f"certified gap                  = {gap:.2%} "
           f"(includes the LP-MIP integrality gap)")
-    print(f"kernel work: {stats['flops'] / 1e12:.2f} TFLOP on "
-          f"{stats['device']}")
     return 0
 
 

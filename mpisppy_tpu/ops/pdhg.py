@@ -648,11 +648,10 @@ class PDHGSolver:
     """
 
     def __init__(self, max_iters=20000, eps=1e-6, check_every=40,
-                 restart_every=16, omega0=1.0, use_pallas="auto",
-                 pallas_tile=8, pallas_interpret=False,
-                 restart_mode="adaptive", restart_beta_sufficient=0.2,
-                 restart_beta_necessary=0.8, compact_threshold=0.0,
-                 hot_dtype=None, sparse_threshold=0.0):
+                 restart_every=16, omega0=1.0, restart_mode="adaptive",
+                 restart_beta_sufficient=0.2, restart_beta_necessary=0.8,
+                 compact_threshold=0.0, hot_dtype=None,
+                 sparse_threshold=0.0):
         # restart_every is in units of `check_every` inner iterations.
         # Under restart_mode="adaptive" it is the FORCED cycle-length
         # cap (a restart fires at the latest every restart_every
@@ -682,18 +681,6 @@ class PDHGSolver:
         # unconverged survivors into a smaller pow2 width bucket;
         # 0.0 disables compaction (solve_compacted == solve)
         self.compact_threshold = float(compact_threshold)
-        if use_pallas == "auto":
-            # measured on TPU v5e (farmer-64, crops_mult 4): XLA's
-            # fused while_loop beats the Pallas chunk kernel ~100x at
-            # these batched-small-matvec shapes — Pallas grid programs
-            # serialize over scenario tiles while XLA vectorizes the
-            # whole batch.  The kernel stays available (explicitly
-            # pass use_pallas=True) for very large per-scenario
-            # problems where one scenario fills VMEM.
-            use_pallas = False
-        self.use_pallas = bool(use_pallas)
-        self.pallas_tile = int(pallas_tile)
-        self.pallas_interpret = bool(pallas_interpret)
         # mixed-precision hot loop (see HOT_DTYPES): None/f64/off keep
         # the historical behavior — the loop runs in the caller's dtype
         if hot_dtype in (None, "", "none", "off", "f64", "float64"):
@@ -739,9 +726,6 @@ class PDHGSolver:
             eps=float(o.get("pdhg_eps", 1e-6)),
             check_every=int(o.get("pdhg_check_every", 40)),
             restart_every=int(o.get("pdhg_restart_every", 16)),
-            use_pallas=o.get("pdhg_use_pallas", "auto"),
-            pallas_tile=int(o.get("pdhg_pallas_tile", 8)),
-            pallas_interpret=bool(o.get("pdhg_pallas_interpret", False)),
             restart_mode=str(o.get("pdhg_restart_mode", "adaptive")),
             restart_beta_sufficient=float(
                 o.get("pdhg_restart_beta_sufficient", 0.2)),
@@ -760,24 +744,21 @@ class PDHGSolver:
         with different compaction policies never alias in caches keyed
         on it, e.g. serve.compile_cache.bucket_key.)"""
         return (self.max_iters, self.eps, self.check_every,
-                self.restart_every, self.omega0, self.use_pallas,
-                self.pallas_tile, self.pallas_interpret,
-                self.restart_mode, self.restart_beta_sufficient,
-                self.restart_beta_necessary, self.compact_threshold,
-                self.hot_dtype, self.sparse_threshold)
+                self.restart_every, self.omega0, self.restart_mode,
+                self.restart_beta_sufficient, self.restart_beta_necessary,
+                self.compact_threshold, self.hot_dtype,
+                self.sparse_threshold)
 
     def clone(self, **overrides):
         """A new solver with this one's full config, selected fields
         overridden — the safe way for callers that re-solve under a
         different budget/precision (spopt._certified_resolve,
         opt.mip._dive_solver) to keep every OTHER knob (restart policy,
-        betas, pallas config) in sync with the parent solver."""
+        betas, compaction, sparsity) in sync with the parent solver."""
         cfg = dict(
             max_iters=self.max_iters, eps=self.eps,
             check_every=self.check_every,
             restart_every=self.restart_every, omega0=self.omega0,
-            use_pallas=self.use_pallas, pallas_tile=self.pallas_tile,
-            pallas_interpret=self.pallas_interpret,
             restart_mode=self.restart_mode,
             restart_beta_sufficient=self.restart_beta_sufficient,
             restart_beta_necessary=self.restart_beta_necessary,
@@ -1076,23 +1057,6 @@ class PDHGSolver:
             """n PDHG iterations; returns final + running sums."""
             sigma = 0.9 * omega / anorm
             tau = 0.9 / (omega * anorm + 0.9 * qmax)
-
-            if self.use_pallas:
-                # the Pallas chunk kernel tiles per-scenario A slabs; a
-                # caller who asked for it gets it compiled or an error,
-                # never a silent XLA or interpret-mode substitute
-                if csum is not None or isinstance(A, SplitA) \
-                        or A.shape[0] != x.shape[0]:
-                    raise ValueError(
-                        "use_pallas=True needs a dense per-scenario "
-                        "(S, M, N) operator and no consensus; this "
-                        "solve's operator is split, shared or "
-                        "consensus — use use_pallas=False")
-                from .pallas_pdhg import fused_chunk
-                return fused_chunk(
-                    A, cs, qs, lbs, ubs, rlo, rhi, x, y,
-                    tau, sigma, n, tile_s=self.pallas_tile,
-                    interpret=self.pallas_interpret)
 
             def body(_, carry):
                 x, y, xs, ys = carry

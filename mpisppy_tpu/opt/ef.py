@@ -149,7 +149,7 @@ class ExtensiveForm(SPOpt):
             # tests/test_precision.py)
             s64 = self.solver.clone(
                 max_iters=max(self.solver.max_iters, 100000),
-                use_pallas=False, hot_dtype=None)
+                hot_dtype=None)
             r64 = s64.solve(
                 prep64,
                 put(c),
@@ -208,9 +208,7 @@ def ef_dual_bound(batch, scenario_names, eps=1e-5, max_iters=100000):
     a valid outer bound at ANY iterate when the batch is an LP with
     all-finite boxes (spopt valid-Ebound rule #1), and measured (UC
     S=50 vs a HiGHS oracle) much tighter than a W-path Lagrangian
-    bound at small PH iteration counts.  Shared by bench.py worker_uc
-    and examples/uc_scale_demo.py so the bench artifact and the demo
-    certify with the same protocol."""
+    bound at small PH iteration counts (examples/uc_scale_demo.py)."""
     import time
 
     t0 = time.time()

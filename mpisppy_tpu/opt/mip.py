@@ -126,7 +126,7 @@ class ExtensiveFormMIP(ExtensiveForm):
         key = ("_dive_solver", max_iters)
         s = self._np_cache.get(key)
         if s is None:
-            # clone: every knob (restart policy, betas, pallas config)
+            # clone: every knob (restart policy, betas, compaction)
             # stays in lockstep with the certified solver's config —
             # except hot_dtype, pinned OFF: dive probes feed bound
             # decisions (prune/accept), which must never rest on a

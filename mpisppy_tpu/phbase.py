@@ -32,7 +32,6 @@ from . import global_toc
 from .ir import ScenarioBatch, SparseSplitA, node_segment_sum
 from .resilience.chaos import ChaosInjector
 from .spopt import SPOpt
-from .utils import mfu as _mfu
 
 
 def _register(cls, data_fields, meta_fields=()):
@@ -637,16 +636,10 @@ class PHBase(SPOpt):
                 self._run_superstep()
         else:
             self._run_superstep()
-        # account the superstep's kernel work (utils/mfu): iters ride
-        # along in the state so no extra device sync is needed beyond
-        # the conv readback below
-        b = self.batch
+        # the superstep's kernel work: iters ride along in the state so
+        # no extra device sync is needed beyond the conv readback below
         it_n = int(self.state.solve_iters)
         rst_n = int(self.state.solve_restarts)
-        self._flops += _mfu.pdhg_flops(
-            it_n, b.num_scens, b.num_rows,
-            b.num_vars, self.solver.check_every,
-            density=self._prep_density(self.prep))
         self._kernel_iters += it_n
         self._restarts_total += rst_n
         if isinstance(self.prep.A, SparseSplitA):

@@ -28,7 +28,6 @@ from .ir import (SparseSplitA, SplitA, bmatvec, delta_idx, delta_runs,
 from .ops.pdhg import (PDHGSolver, PreparedBatch, prepare_batch,
                        prepare_batch_split, prepare_split_native)
 from .spbase import SPBase
-from .utils import mfu as _mfu
 
 
 def _host(a):
@@ -66,8 +65,7 @@ class SPOpt(SPBase):
             global_toc("Preparing batch (Ruiz scaling + ||A|| estimate)")
             self.prep = self._build_prep(hot=self.solver.hot_dtype)
         # density of the shared constraint block actually carried by the
-        # prep (None for non-split preps) — bench reports it, and the
-        # FLOP accounting debits sparse matvecs by it
+        # prep (None for non-split preps), reported by pdhg_stats
         self._shared_nnz_frac = (float(shared_density(self.prep.A))
                                  if isinstance(self.prep.A, SplitA)
                                  else None)
@@ -77,12 +75,10 @@ class SPOpt(SPBase):
         self._y_warm = None
         self._named_warm = {}
         self._solve_times = []
-        self._flops = 0.0          # accumulated kernel FLOPs (utils/mfu)
         self._solve_wall = 0.0     # accumulated timed solve seconds
         self._certify_wall = 0.0   # seconds inside f64 certified re-solves
         self._kernel_iters = 0     # accumulated PDHG kernel iterations
         self._restarts_total = 0   # accumulated PDHG restart events
-        self._flops_saved = 0.0    # est. FLOPs avoided by compaction
         self._active_traj = []     # last compacted solve's trajectory
         self._active_fraction = 1.0  # last solve's final active fraction
         self._promotions = 0       # solves promoted hot-dtype -> full
@@ -182,15 +178,6 @@ class SPOpt(SPBase):
                 self._tel.registry.counter("pdhg.promotions").inc()
         return solver, prep
 
-    @staticmethod
-    def _prep_density(prep):
-        """FLOP discount for the matvec model: the BCOO path does
-        ~density x the dense shared-block work; dense preps pay full
-        price."""
-        if isinstance(prep.A, SparseSplitA):
-            return float(prep.A.shared_nnz_frac)
-        return 1.0
-
     # -- hot path ---------------------------------------------------------
     def solve_loop(self, c=None, qdiag=None, lb=None, ub=None,
                    warm=True, dtiming=False, certify=False, eps=None,
@@ -271,7 +258,6 @@ class SPOpt(SPBase):
                 # full-precision pair (monotone under the ladder/Gapper
                 # schedules, so this re-routes at most once per run)
                 solver, prep = self.active_solver_prep(eps_arg)
-            dens = self._prep_density(prep)
             args = (prep,
                     b.c if c is None else c,
                     b.qdiag if qdiag is None else qdiag,
@@ -290,28 +276,14 @@ class SPOpt(SPBase):
                 full = float(max(int(np.sum(_host(b.prob) > 0)), 1))
                 self._active_fraction = (traj[-1]["active"] / full
                                          if traj else 0.0)
-                # FLOPs the compacted segments did NOT spend on rows the
-                # full-width solve would have carried
-                saved = sum(
-                    _mfu.pdhg_flops(t["seg_iters"],
-                                    b.num_scens - t["width"],
-                                    b.num_rows, b.num_vars,
-                                    solver.check_every, density=dens)
-                    for t in traj if t["width"] < b.num_scens)
-                self._flops_saved += saved
             else:
                 res = solver.solve(*args, **kw, iters_cap=iters_cap)
-                saved = 0.0
                 self._active_fraction = float(
                     np.sum(~_host(res.converged)
                            & (_host(b.prob) > 0))
                     / max(int(np.sum(_host(b.prob) > 0)), 1))
             it_n = int(_host(res.iters))
             rst_n = int(np.sum(_host(res.restarts)))
-            # net of compaction savings: saved counts work NOT done
-            self._flops += _mfu.pdhg_flops(
-                it_n, b.num_scens, b.num_rows, b.num_vars,
-                solver.check_every, density=dens) - saved
             if isinstance(prep.A, SparseSplitA):
                 # two shared-block products (forward + transpose) per
                 # inner iteration route through jax.experimental.sparse
@@ -346,8 +318,6 @@ class SPOpt(SPBase):
             r.gauge("pdhg.active_scenarios").set(
                 self._active_fraction
                 * int(np.sum(_host(b.prob) > 0)))
-            if saved:
-                r.counter("pdhg.flops_saved").inc(saved)
             if isinstance(prep.A, SparseSplitA):
                 r.counter("pdhg.sparse_matvecs").inc(2 * it_n)
             if rst_n:
@@ -433,14 +403,12 @@ class SPOpt(SPBase):
         if self._solver64 is None or \
                 self._solver64.max_iters != cert_iters:
             # clone: keeps the restart policy/betas (and every future
-            # knob) in lockstep with the fast solver's config; the f64
-            # fallback typically runs on host CPU, where the Pallas
-            # kernel has no business.  hot_dtype is pinned OFF: the
-            # certified verdict is this path's whole purpose, so it
-            # never inherits a low-precision hot loop (AST-guarded in
-            # tests/test_precision.py).
+            # knob) in lockstep with the fast solver's config.
+            # hot_dtype is pinned OFF: the certified verdict is this
+            # path's whole purpose, so it never inherits a low-precision
+            # hot loop (AST-guarded in tests/test_precision.py).
             self._solver64 = self.solver.clone(
-                max_iters=cert_iters, use_pallas=False, hot_dtype=None)
+                max_iters=cert_iters, hot_dtype=None)
         try:
             cpu = jax.devices("cpu")[0]
         except RuntimeError:
@@ -510,9 +478,6 @@ class SPOpt(SPBase):
                 x0=put(sub["x0"]), y0=put(sub["y0"]),
                 eps=float(self.solver_eps))
             jax.block_until_ready(r64.x)
-        self._flops += _mfu.pdhg_flops(
-            int(r64.iters), idx.size, b.num_rows, b.num_vars,
-            self.solver.check_every)
         dt_cert = time.time() - t_cert
         self._certify_wall += dt_cert
         if self._tel.enabled:
@@ -573,42 +538,30 @@ class SPOpt(SPBase):
         return jnp.sum(vals)
 
     def reset_solve_stats(self):
-        """Zero the FLOP/wall accumulators (benchmarks call this after
-        compile warmup so the reported MFU covers the timed region)."""
-        self._flops = 0.0
+        """Zero the wall-clock and work accumulators (benchmarks call
+        this after compile warmup so the counts cover the timed
+        region)."""
         self._solve_wall = 0.0
         self._certify_wall = 0.0
         self._kernel_iters = 0
         self._solve_times = []
         self._restarts_total = 0
-        self._flops_saved = 0.0
         self._active_traj = []
         self._active_fraction = 1.0
         self._promotions = 0
         self._sparse_matvecs = 0
 
-    def _kernel_dtype(self):
-        """dtype the hot-loop matvec FLOPs actually execute in: the hot
-        STORAGE dtype when configured (bf16 for bf16x — that is the
-        multiply datapath), else the batch dtype."""
-        from .ops.pdhg import HOT_DTYPES
-        if self.solver.hot_dtype is not None:
-            return HOT_DTYPES[self.solver.hot_dtype][0]
-        return str(jnp.asarray(self.batch.c).dtype)
-
     def pdhg_stats(self):
         """Adaptive-work counters across all solve_loop calls since the
-        last reset: total inner iterations, restart events, estimated
-        FLOPs saved by compaction, the final active fraction, the last
-        compacted solve's active-fraction trajectory (one entry per
-        segment), plus the precision/sparsity state: the configured
-        hot dtype, solves promoted to full precision, sparse matvec
-        count and the shared-block density (None when the prep carries
-        no split matrix).  bench.py surfaces these."""
+        last reset: total inner iterations, restart events, the final
+        active fraction, the last compacted solve's active-fraction
+        trajectory (one entry per segment), plus the precision/sparsity
+        state: the configured hot dtype, solves promoted to full
+        precision, sparse matvec count and the shared-block density
+        (None when the prep carries no split matrix)."""
         return {
             "inner_iters": int(self._kernel_iters),
             "restarts_total": int(self._restarts_total),
-            "flops_saved": float(self._flops_saved),
             "active_fraction_final": float(self._active_fraction),
             "active_fraction_traj": list(self._active_traj),
             "hot_dtype": self.solver.hot_dtype,
@@ -618,21 +571,12 @@ class SPOpt(SPBase):
         }
 
     def solve_stats(self):
-        """Accumulated kernel FLOPs / wall-clock / MFU across all
-        solve_loop calls (dtiming analog, extended with hardware
-        utilization — see utils/mfu.py).  The MFU peak is dtype-aware:
-        a hot-dtype run is measured against the low-precision peak its
-        matvecs actually target."""
-        dev = jax.devices()[0]
-        dt = self._kernel_dtype()
-        u = _mfu.mfu(self._flops, self._solve_wall, dev, dtype=dt)
+        """Accumulated wall-clock across all solve_loop calls (dtiming
+        analog): seconds in the timed solves, and the seconds spent in
+        float64 certified re-solves."""
         return {
-            "flops": self._flops,
             "solve_wall_s": self._solve_wall,
             "certify_wall_s": self._certify_wall,
-            "mfu": u,
-            "dtype": dt,
-            "device": getattr(dev, "device_kind", dev.platform),
         }
 
     def feas_prob(self, res, tol=None):
@@ -860,9 +804,6 @@ class SPOpt(SPBase):
             y0 = jnp.zeros_like(b.row_lo)
         res, (rlo, rhi, oc) = cache["jit"](
             vals, x0, y0, self.solver_eps)
-        self._flops += _mfu.pdhg_flops(
-            int(res.iters), b.num_scens, b.num_rows, b.num_vars,
-            self.solver.check_every)
         if certify == "auto":
             certify = not (self.feas_prob(res, tol=tol) > 1.0 - 1e-6)
         if certify:
@@ -977,9 +918,6 @@ class SPOpt(SPBase):
         res, objs = stack["jit"](jnp.asarray(cands), x0, y0,
                                  eps, iters_cap)
         jax.block_until_ready(res.x)
-        self._flops += _mfu.pdhg_flops(
-            int(res.iters), k * b.num_scens, b.num_rows, b.num_vars,
-            self.solver.check_every)
         self._solve_wall += time.time() - t0
         self._named_warm[warm] = (res.x, res.y)
         tol = tol or 10 * float(self.solver_eps)

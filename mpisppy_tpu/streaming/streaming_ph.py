@@ -522,7 +522,7 @@ class StreamingPH(PHBase):
         return self.conv, None, trivial
 
     def stream_stats(self):
-        """Streaming run facts for bench.py / callers."""
+        """Streaming run facts for callers."""
         st = self.stream.stats()
         steps = int(self.state.it) if self.state is not None else 0
         out = {

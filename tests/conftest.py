@@ -44,7 +44,7 @@ def pytest_configure(config):
         "guard); these RUN under tier-1's `-m 'not slow'`")
     config.addinivalue_line(
         "markers", "precision: mixed-precision hot-loop tests "
-        "(hot_dtype, promotion, sparse matvecs, dtype-aware MFU); "
+        "(hot_dtype, promotion, sparse matvecs, solver statistics); "
         "these RUN under tier-1's `-m 'not slow'`")
     config.addinivalue_line(
         "markers", "streaming: minibatch randomized-PH streaming tests "

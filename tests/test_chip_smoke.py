@@ -61,17 +61,3 @@ def test_phase_mpmd_collective_four_devices():
     out = chip_smoke.phase_mpmd_collective(jax.devices()[:4], S=3,
                                            iters=3)
     assert out["counters"]["wheel_corrupt_reads"] == 0
-
-
-def test_bench_worker_requires_a_device(monkeypatch):
-    """bench.py measures the chip: its worker refuses the CPU unless
-    JAX_PLATFORMS=cpu asks for it, and main() exits non-zero when the
-    worker yields no result."""
-    import bench
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench._require_device()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setenv("BENCH_TIMEOUT", "0.01")
-    with pytest.raises(SystemExit, match="timed out"):
-        bench.main()

@@ -9,6 +9,7 @@ subset below asserts the >=20% aggregate on its three fastest members.
 """
 
 import ast
+import inspect
 import os
 
 import numpy as np
@@ -76,6 +77,7 @@ def test_clone_and_config_key():
     ka, kb = s.config_key(), c.config_key()
     assert ka != kb
     assert [a for a, b in zip(ka, kb) if a != b] == [s.max_iters]
+    assert len(ka) == len(inspect.signature(PDHGSolver).parameters)
     # the new knobs are IN the key (configs must never alias in caches)
     assert s.config_key() != s.clone(restart_mode="fixed").config_key()
     assert s.config_key() != \
@@ -229,27 +231,6 @@ def test_gather_prep_keeps_shared_leaves():
     g2 = _gather_prep(shared, ii)
     assert g2.d_row.shape[0] == 1        # untouched broadcast leaf
     assert g2.row_lo.shape[0] == 2
-
-
-def test_pallas_kernel_on_compacted_slab():
-    """The Pallas fused-chunk path (interpret mode) must match the jnp
-    path on a gathered, non-pow2-tile slab — the shape compaction
-    produces (width 4 slab under the default tile_s=8 forces the
-    even-divisor tiling fallback)."""
-    b = farmer.build_batch(8)
-    prep = prepare_batch(b.A, b.row_lo, b.row_hi)
-    ii = jnp.asarray([0, 2, 5, 6], jnp.int32)
-    gp = _gather_prep(prep, ii)
-    args = (b.c[ii], b.qdiag[ii], b.lb[ii], b.ub[ii])
-    kw = {"obj_const": b.obj_const[ii]}
-    sp = PDHGSolver(max_iters=20000, eps=1e-7, use_pallas=True,
-                    pallas_tile=8, pallas_interpret=True)
-    sj = sp.clone(use_pallas=False)
-    rp = sp.solve(gp, *args, **kw)
-    rj = sj.solve(gp, *args, **kw)
-    assert bool(np.all(np.asarray(rp.converged)))
-    assert np.allclose(np.asarray(rp.obj), np.asarray(rj.obj),
-                       rtol=1e-5, atol=1e-3)
 
 
 # --------------------------------------------------------------------------

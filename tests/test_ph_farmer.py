@@ -156,8 +156,7 @@ def test_iter0_certify_off_and_certify_budget(monkeypatch):
 def test_farmer_4096_scenarios_sharded_gap():
     """farmer-10k tier (BASELINE.md target row 'farmer, 10,000 scen')
     at test scale: S=4096 sharded over the 8-virtual-device mesh, PH
-    to a VERIFIED <=1% outer/inner gap — the same protocol the
-    BENCH_SCENS=10000 artifact runs on the TPU."""
+    to a VERIFIED <=1% outer/inner gap."""
     S = 4096
     b = farmer.build_batch(S)
     ph = PH({"defaultPHrho": 1.0, "PHIterLimit": 60, "convthresh": 0.0,

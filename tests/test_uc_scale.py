@@ -83,10 +83,9 @@ def test_uc_1000_reference_scale_fits():
 def test_uc_1000_scenarios_slow():
     """1000-wind-scenario tier (VERDICT r3 item 6): PH + Lagrangian +
     threshold-commitment xhat on a 6-unit fleet at S=1000, all batched
-    through the shared-A matmul path, to a MEASURED gap.  (The
-    21-unit/24 h full instance is the TPU bench entry —
-    BENCH_MODEL=uc1000 in bench.py; this tier keeps the per-scenario
-    LP small enough for the 1-core CPU test budget.)"""
+    through the shared-A matmul path, to a MEASURED gap.  (This tier
+    keeps the per-scenario LP small enough for the 1-core CPU test
+    budget; the 21-unit/24 h full instance has no CPU tier.)"""
     S = 1000
     b = uc.build_batch(S, H=6)
     assert b.shared_A
@@ -251,7 +250,7 @@ def test_uc_commitment_repair_windows():
 
 def test_ef_dual_bound_validity():
     """The shared EF-dual outer bound helper (opt/ef.ef_dual_bound,
-    used by bench.py worker_uc and uc_scale_demo) must lower-bound any
+    used by uc_scale_demo) must lower-bound any
     feasible integer commitment's objective, and must beat the iter-0
     trivial bound's slack at small iteration counts (the calibration
     that cut the r4 UC artifact's reported gap from 17.7% to 4.1%)."""
@@ -326,7 +325,7 @@ def test_infeasible_uc_detected_without_iter0_certify():
     independent validation — iter0 feasible mass collapses and every
     recovered-commitment candidate fails the feasibility screen, so
     the bench reports 'no feasible commitment candidate' instead of a
-    gap (bench.py worker_uc)."""
+    gap."""
     import dataclasses
 
     S, H = 8, 4
